@@ -1,7 +1,3 @@
 """Reproduction package: the run-time reconfigurable multi-precision
-multiplier (Arish & Sharma 2019) grown into a jax_pallas system.
-
-Importing ``repro`` installs the jax version-compat shims first so every
-module (and the test suite) can target one API surface.  See DESIGN.md.
-"""
-from repro import compat as _compat  # noqa: F401  (side-effect import)
+multiplier (Arish & Sharma 2019) grown into a jax_pallas system.  See
+DESIGN.md."""

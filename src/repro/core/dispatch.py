@@ -5,7 +5,8 @@ Every ``mp_matmul`` call funnels through :func:`dispatch`, which routes to a
 registered backend:
 
   ref               pure-jnp limb matmuls (XLA fuses; oracle + dry-run)
-  pallas            fused Pallas kernel, block sizes from the autotune table
+  pallas            fused Pallas kernel compiled by Mosaic, block sizes from
+                    the autotune table; TPU only (raises elsewhere)
   pallas_interpret  same kernel, interpreter mode (CPU validation)
   sharded           shard_map data-parallel path: the contraction (K) dim
                     shards over a 1-D device mesh, each device accumulates
@@ -115,11 +116,32 @@ def _tuned_blocks(a: Operand, b: Operand, fmt: MPFormat, interpret: bool
     return blocks if blocks is not None else (None, None, None)
 
 
+def kernel_interpret(backend: str) -> bool:
+    """The ``interpret`` flag for a Pallas backend name.
+
+    ``pallas`` is compiled Mosaic and needs a TPU: on any other host it
+    raises instead of quietly running the interpreter, so a run that asked
+    for the kernels either runs them on the chip or fails.
+    ``pallas_interpret`` runs the same kernels in the interpreter (CPU
+    validation)."""
+    if backend == "pallas_interpret":
+        return True
+    if backend != "pallas":
+        raise ValueError(f"{backend!r} is not a Pallas backend")
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"backend 'pallas' compiles Mosaic kernels for a TPU, but JAX's "
+            f"backend here is {jax.default_backend()!r}; use "
+            f"backend='pallas_interpret' to run the kernels in the "
+            f"interpreter")
+    return False
+
+
 def _run_pallas(a: Operand, b: Operand, fmt: MPFormat, out_dtype,
-                *, interpret: bool):
+                *, backend: str):
     from repro.kernels import ops as pallas_backend  # deferred: imports pallas
 
-    interpret = interpret or jax.default_backend() == "cpu"
+    interpret = kernel_interpret(backend)
     bm, bk, bn = _tuned_blocks(a, b, fmt, interpret)
     return pallas_backend.mp_matmul_pallas(
         a, b, fmt, out_dtype=out_dtype, interpret=interpret,
@@ -156,14 +178,9 @@ def _bound_axis_names() -> Tuple:
     Nested shard_map is unsupported — a sharded-backend matmul inside e.g.
     the MoE expert-parallel body must fall back to local compute (the outer
     scope already owns the devices)."""
-    try:
-        from jax._src import core as _core  # no public accessor on old jax
+    from jax._src import core as _core  # no public accessor
 
-        if hasattr(_core, "unsafe_get_axis_names"):
-            return tuple(_core.unsafe_get_axis_names())
-        return tuple(_core.get_axis_env().axis_names())
-    except Exception:
-        return ()
+    return tuple(_core.unsafe_get_axis_names())
 
 
 def _run_sharded(a: Operand, b: Operand, fmt: MPFormat, out_dtype,
@@ -204,9 +221,9 @@ def _run_sharded(a: Operand, b: Operand, fmt: MPFormat, out_dtype,
 _REGISTRY: Dict[str, Callable] = {
     "ref": lambda a, b, fmt, out_dtype: _run_ref(a, b, fmt, out_dtype),
     "pallas": lambda a, b, fmt, out_dtype: _run_pallas(
-        a, b, fmt, out_dtype, interpret=False),
+        a, b, fmt, out_dtype, backend="pallas"),
     "pallas_interpret": lambda a, b, fmt, out_dtype: _run_pallas(
-        a, b, fmt, out_dtype, interpret=True),
+        a, b, fmt, out_dtype, backend="pallas_interpret"),
     "sharded": lambda a, b, fmt, out_dtype: _run_sharded(
         a, b, fmt, out_dtype),
 }
@@ -330,7 +347,7 @@ def dispatch_fused(
     if name in ("pallas", "pallas_interpret"):
         from repro.kernels import ops as pallas_backend  # deferred: pallas
 
-        interpret = name == "pallas_interpret" or jax.default_backend() == "cpu"
+        interpret = kernel_interpret(name)
         bm, bk, bn = _tuned_blocks_fused(
             x, ws, fmt, interpret, gate, biases is not None,
             residual is not None)
@@ -397,7 +414,7 @@ def dispatch_attention(
     if name in ("pallas", "pallas_interpret"):
         from repro.kernels import mp_attention as attn_kernels
 
-        interpret = name == "pallas_interpret" or jax.default_backend() == "cpu"
+        interpret = kernel_interpret(name)
         B, S, H, Dh = q.shape
         bq, bkv = block_q, block_kv
         if bq is None and bkv is None:
@@ -532,7 +549,7 @@ def dispatch_mixed_matmul(
     if name in ("pallas", "pallas_interpret"):
         from repro.kernels import ops as pallas_backend  # deferred: pallas
 
-        interpret = name == "pallas_interpret" or jax.default_backend() == "cpu"
+        interpret = kernel_interpret(name)
         return pallas_backend.mp_mixed_matmul_pallas(
             a, b, env, lane_n, lane_ord, out_dtype=out_dtype,
             interpret=interpret)
@@ -651,7 +668,7 @@ def dispatch_mixed_paged_attention(
     if name in ("pallas", "pallas_interpret"):
         from repro.kernels import mp_attention as attn_kernels
 
-        interpret = name == "pallas_interpret" or jax.default_backend() == "cpu"
+        interpret = kernel_interpret(name)
         out = attn_kernels.mp_mixed_paged_attention_pallas(
             q.reshape(B, H, Dh), k_pool, v_pool, block_table, lengths,
             env_qk, env_pv, lane_qk_n, lane_qk_ord, lane_pv_n, lane_pv_ord,
@@ -702,7 +719,7 @@ def dispatch_paged_attention(
     if name in ("pallas", "pallas_interpret") and not is_auto_fmt:
         from repro.kernels import mp_attention as attn_kernels
 
-        interpret = name == "pallas_interpret" or jax.default_backend() == "cpu"
+        interpret = kernel_interpret(name)
         out = attn_kernels.mp_paged_attention_pallas(
             q.reshape(B, H, Dh), k_pool, v_pool, block_table, lengths,
             mode_qk, mode_pv, scale=scale, interpret=interpret)

@@ -64,8 +64,9 @@ class PrelimbedWeight(NamedTuple):
 
 
 def prelimb_weight(w: jax.Array, n_limbs: int) -> PrelimbedWeight:
-    """Pure-jnp prelimb of a (..., K, N) weight (serving uses the Pallas
-    decompose kernel via kernels/ops.decompose_weights; this is the oracle)."""
+    """Pure-jnp prelimb of a (..., K, N) weight -> (..., n_limbs, K, N)
+    (what the serving engine runs once per policy; the Pallas decompose
+    kernel, kernels/ops.decompose_weights, gives the same limbs)."""
     stacked = decompose(w, n_limbs)  # (L, ..., K, N)
     order = tuple(range(1, stacked.ndim - 2)) + (0, stacked.ndim - 2,
                                                  stacked.ndim - 1)
@@ -83,6 +84,19 @@ def dd_to_f64(d: DD) -> np.ndarray:
     return np.asarray(d.hi, dtype=np.float64) + np.asarray(d.lo, dtype=np.float64)
 
 
+def round_to_bf16(x: jax.Array) -> jax.Array:
+    """fp32 -> the nearest bf16 value (round-to-nearest-even), kept in fp32.
+
+    The same value as ``x.astype(bfloat16).astype(float32)``, but computed
+    on the bit pattern: XLA on TPU may drop that convert pair inside a
+    fusion (excess precision), and then every residual ``x - bf16(x)`` — so
+    every limb past the first — comes out zero."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    u = (u + (((u >> 16) & 1) + 0x7FFF)) & np.uint32(0xFFFF0000)
+    return jnp.where(jnp.isnan(x), x,
+                     jax.lax.bitcast_convert_type(u, jnp.float32))
+
+
 def decompose(x: jax.Array, n_limbs: int) -> jax.Array:
     """fp32 -> stacked bf16 limbs, shape (n_limbs, *x.shape).
 
@@ -96,9 +110,9 @@ def decompose(x: jax.Array, n_limbs: int) -> jax.Array:
     limbs = []
     r = x
     for _ in range(n_limbs):
-        li = r.astype(jnp.bfloat16)
-        limbs.append(li)
-        r = r - li.astype(jnp.float32)
+        hi = round_to_bf16(r)
+        limbs.append(hi.astype(jnp.bfloat16))  # exact
+        r = r - hi
     return jnp.stack(limbs)
 
 
@@ -111,9 +125,9 @@ def decompose_dd(x: DD, n_limbs: int) -> jax.Array:
     limbs = []
     r = x.hi.astype(jnp.float32)
     for i in range(n_limbs):
-        li = r.astype(jnp.bfloat16)
-        limbs.append(li)
-        r = r - li.astype(jnp.float32)
+        hi = round_to_bf16(r)
+        limbs.append(hi.astype(jnp.bfloat16))
+        r = r - hi
         if i == 2:  # residual of hi has decayed to lo's scale: fold lo in
             r = r + x.lo.astype(jnp.float32)
     return jnp.stack(limbs)
@@ -141,7 +155,7 @@ def residual_scale(x: jax.Array, n_limbs: int) -> jax.Array:
     x = x.astype(jnp.float32)
     r = x
     for _ in range(n_limbs):
-        r = r - r.astype(jnp.bfloat16).astype(jnp.float32)
+        r = r - round_to_bf16(r)
     scale = jnp.maximum(jnp.max(jnp.abs(x)), jnp.finfo(jnp.float32).tiny)
     return jnp.max(jnp.abs(r)) / scale
 
@@ -162,7 +176,7 @@ def significant_limbs(
     needed = jnp.int32(1)
     r = x
     for k in range(1, max_limbs):  # after k limbs, is the residual too big?
-        r = r - r.astype(jnp.bfloat16).astype(jnp.float32)
+        r = r - round_to_bf16(r)
         too_big = jnp.max(jnp.abs(r)) > tol * scale
         # if the residual after k limbs is still too big, need at least k+1
         needed = jnp.maximum(needed, jnp.where(too_big, jnp.int32(k + 1), 1))

@@ -46,9 +46,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import FormatLike, resolve
 from repro.kernels import ref as ref_backend
-from repro.kernels.mp_matmul import _compiler_params
+from repro.kernels.mp_matmul import _compiler_params, _extract_limbs
 
 NEG_INF = ref_backend.ATTN_NEG_INF
+
+# grid (B, W): slots are independent; the (m, d, acc) scratch accumulates
+# over a slot's table columns, so W runs in order
+PAGED_SEMANTICS = ("parallel", "arbitrary")
 
 # default flash tile sizes (q rows x kv columns); autotune sweeps around them
 DEFAULT_BLOCK_Q = 128
@@ -116,11 +120,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, d_scr, acc_scr, *,
         valid = k_pos < t_real
         if causal:
             valid = valid & (q_pos >= k_pos)
-        logits = ref_backend.attn_qk_logits(q, kb, fmt_qk)
+        logits = ref_backend.attn_qk_logits(q, kb, fmt_qk,
+                                            split=_extract_limbs)
         logits = jnp.where(valid, logits, NEG_INF)
         m, d, acc = ref_backend.online_softmax_update(
             m_scr[:, 0], d_scr[:, 0], acc_scr[...], logits, vb, fmt_pv,
-            p_mask=valid)
+            p_mask=valid, split=_extract_limbs)
         m_scr[...] = jnp.broadcast_to(m[:, None], m_scr.shape)
         d_scr[...] = jnp.broadcast_to(d[:, None], d_scr.shape)
         acc_scr[...] = acc
@@ -215,7 +220,9 @@ def mp_attention_pallas(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, Dp), jnp.float32),
         ],
-        compiler_params=_compiler_params(),
+        # kv steps carry the (m, d, acc) scratch: innermost axis in order
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary")),
         cost_estimate=cost,
         interpret=interpret,
     )
@@ -257,11 +264,12 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         ms, ds, accs = [], [], []
         for kh in range(hk):  # static GQA loop: 2-D MXU work per kv head
             sl = slice(kh * n_rep, (kh + 1) * n_rep)
-            logits = ref_backend.attn_qk_logits(q[sl], kb[:, kh], fmt_qk)
+            logits = ref_backend.attn_qk_logits(q[sl], kb[:, kh], fmt_qk,
+                                                split=_extract_limbs)
             logits = jnp.where(valid, logits, NEG_INF)
             m, d, acc = ref_backend.online_softmax_update(
                 m_scr[sl, 0], d_scr[sl, 0], acc_scr[sl], logits,
-                vb[:, kh], fmt_pv, p_mask=valid)
+                vb[:, kh], fmt_pv, p_mask=valid, split=_extract_limbs)
             ms.append(m)
             ds.append(d)
             accs.append(acc)
@@ -333,7 +341,7 @@ def mp_paged_attention_pallas(
             scale=scale, out_dtype=out_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), out_dtype),
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(PAGED_SEMANTICS),
         interpret=interpret,
     )
     return call(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
@@ -382,11 +390,12 @@ def _mixed_paged_kernel(tbl_ref, len_ref, lane_ref, q_ref, k_ref, v_ref,
         for kh in range(hk):  # static GQA loop: 2-D MXU work per kv head
             sl = slice(kh * n_rep, (kh + 1) * n_rep)
             logits = ref_backend.masked_attn_qk_logits(
-                q[sl], kb[:, kh], env_qk, n_qk, ord_qk)
+                q[sl], kb[:, kh], env_qk, n_qk, ord_qk, split=_extract_limbs)
             logits = jnp.where(valid, logits, NEG_INF)
             m, d, acc = ref_backend.masked_online_softmax_update(
                 m_scr[sl, 0], d_scr[sl, 0], acc_scr[sl], logits,
-                vb[:, kh], env_pv, n_pv, ord_pv, p_mask=valid)
+                vb[:, kh], env_pv, n_pv, ord_pv, p_mask=valid,
+                split=_extract_limbs)
             ms.append(m)
             ds.append(d)
             accs.append(acc)
@@ -466,7 +475,7 @@ def mp_mixed_paged_attention_pallas(
             scale=scale, out_dtype=out_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), out_dtype),
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(PAGED_SEMANTICS),
         interpret=interpret,
     )
     return call(block_table.astype(jnp.int32), lengths.astype(jnp.int32),

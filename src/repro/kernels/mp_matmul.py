@@ -43,9 +43,24 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.formats import FormatLike, MPFormat, resolve
 from repro.kernels import ref as ref_backend
 
+# grid (Mi, Nj, Kk): output tiles are independent, K steps accumulate into
+# the VMEM scratch and must run in order
+MATMUL_SEMANTICS = ("parallel", "parallel", "arbitrary")
+
+
+def _compiler_params(semantics) -> pltpu.CompilerParams:
+    """Mosaic parameters with one dimension semantic per grid axis:
+    "arbitrary" for an axis a scratch accumulator carries across, else
+    "parallel".  Mosaic refuses a tuple whose length is not the grid's."""
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
+
 
 def _extract_limbs(x: jax.Array, n_limbs: int) -> list[jax.Array]:
-    """On-the-fly limb cascade (VPU): f32 tile -> n_limbs bf16 tiles."""
+    """On-the-fly limb cascade (VPU): f32 tile -> n_limbs bf16 tiles.
+
+    Mosaic keeps the f32->bf16->f32 convert pair, so kernels need none of
+    ``limbs.round_to_bf16``'s bit work, which only XLA-side cascades pay
+    for (XLA on TPU may drop the pair)."""
     limbs = []
     r = x
     for i in range(n_limbs):
@@ -349,22 +364,10 @@ def build_fused_multi_call(
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((n_out, n_orders, bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(MATMUL_SEMANTICS),
         cost_estimate=cost,
         interpret=interpret,
     )
-
-
-def _compiler_params():
-    for cls_name in ("CompilerParams", "TPUCompilerParams"):  # API drift guard
-        cls = getattr(pltpu, cls_name, None)
-        if cls is None:
-            continue
-        try:
-            return cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except TypeError:
-            continue
-    return None
 
 
 KERNEL_VARIANTS = ("fused", "prelimbed_b", "prelimbed_both")
@@ -430,7 +433,7 @@ def build_fused_call(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((n_orders, bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(MATMUL_SEMANTICS),
         cost_estimate=cost,
         interpret=interpret,
     )
@@ -468,7 +471,7 @@ def build_prelimbed_call(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((n_orders, bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(MATMUL_SEMANTICS),
         interpret=interpret,
     )
 
@@ -502,7 +505,7 @@ def build_mixed_prelimbed_call(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((n_orders, bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(MATMUL_SEMANTICS),
         interpret=interpret,
     )
 
@@ -529,6 +532,6 @@ def build_decompose_call(
         in_specs=[pl.BlockSpec((br, bc), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((n_limbs, br, bc), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((n_limbs, R, C), jnp.bfloat16),
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(("parallel", "parallel")),
         interpret=interpret,
     )

@@ -166,26 +166,27 @@ def masked_matmul_ref(a: Operand, b: Operand, env, lane_n, lane_ord, *,
 
 
 def masked_attn_qk_logits(q: jax.Array, k: jax.Array, env, lane_n,
-                          lane_ord) -> jax.Array:
+                          lane_ord, *, split=limbs_lib.decompose) -> jax.Array:
     """Per-lane :func:`attn_qk_logits`: the same untransposed contraction
     fed through the masked cascade (shared by the ref mixed decode path and
     the Pallas mixed paged kernel, where ``lane_n`` is the program's
     scalar-prefetched per-slot value)."""
-    al = limbs_lib.decompose(q, env.n_limbs)
-    bl = limbs_lib.decompose(k, env.n_limbs)
+    al = split(q, env.n_limbs)
+    bl = split(k, env.n_limbs)
     return masked_matmul_limbs(al, bl, env, lane_n, lane_ord, jnp.float32,
                                dot=_dot_nt)
 
 
 def masked_attn_pv(p: jax.Array, v: jax.Array, env, lane_n,
-                   lane_ord) -> jax.Array:
-    al = limbs_lib.decompose(p, env.n_limbs)
-    bl = limbs_lib.decompose(v, env.n_limbs)
+                   lane_ord, *, split=limbs_lib.decompose) -> jax.Array:
+    al = split(p, env.n_limbs)
+    bl = split(v, env.n_limbs)
     return masked_matmul_limbs(al, bl, env, lane_n, lane_ord, jnp.float32)
 
 
 def masked_online_softmax_update(m, d, acc, logits, v, env_pv, lane_n,
-                                 lane_ord, *, p_mask=None):
+                                 lane_ord, *, p_mask=None,
+                                 split=limbs_lib.decompose):
     """:func:`online_softmax_update` with the P·V contraction at per-lane
     depth — the softmax bookkeeping itself is format-free and unchanged."""
     m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
@@ -195,7 +196,7 @@ def masked_online_softmax_update(m, d, acc, logits, v, env_pv, lane_n,
     alpha = jnp.exp(m - m_new)
     d_new = d * alpha + jnp.sum(p, axis=-1)
     acc_new = acc * alpha[..., None] \
-        + masked_attn_pv(p, v, env_pv, lane_n, lane_ord)
+        + masked_attn_pv(p, v, env_pv, lane_n, lane_ord, split=split)
     return m_new, d_new, acc_new
 
 
@@ -399,29 +400,35 @@ def _dot_nt(a: jax.Array, b: jax.Array) -> jax.Array:
     return jax.lax.dot_general(a, b, dn, preferred_element_type=jnp.float32)
 
 
-def attn_qk_logits(q: jax.Array, k: jax.Array, mode: FormatLike) -> jax.Array:
+def attn_qk_logits(q: jax.Array, k: jax.Array, mode: FormatLike, *,
+                   split=limbs_lib.decompose) -> jax.Array:
     """Attention logits for one block pair at the QK format:
     q (..., M, D) f32 (pre-scaled), k (..., T, D) f32 -> (..., M, T) f32.
     The limb cascade runs on both operands (activations x activations —
     unlike the dense layers there is no static weight side to pre-limb);
     accumulation is :func:`_matmul_limbs`' own discipline, with the
-    untransposed contraction plugged in as the limb-pair product."""
+    untransposed contraction plugged in as the limb-pair product.
+
+    ``split`` extracts the limbs: ``limbs.decompose`` for XLA, the Pallas
+    kernels' own convert-pair cascade inside Mosaic."""
     s = resolve(mode)
-    al = limbs_lib.decompose(q, s.n_limbs)
-    bl = limbs_lib.decompose(k, s.n_limbs)
+    al = split(q, s.n_limbs)
+    bl = split(k, s.n_limbs)
     return _matmul_limbs(al, bl, s, jnp.float32, dot=_dot_nt)
 
 
-def attn_pv(p: jax.Array, v: jax.Array, mode: FormatLike) -> jax.Array:
+def attn_pv(p: jax.Array, v: jax.Array, mode: FormatLike, *,
+            split=limbs_lib.decompose) -> jax.Array:
     """Probability-value contraction at the PV format:
     p (..., M, T) f32, v (..., T, D) f32 -> (..., M, D) f32."""
     s = resolve(mode)
-    al = limbs_lib.decompose(p, s.n_limbs)
-    bl = limbs_lib.decompose(v, s.n_limbs)
+    al = split(p, s.n_limbs)
+    bl = split(v, s.n_limbs)
     return _matmul_limbs(al, bl, s, jnp.float32)
 
 
-def online_softmax_update(m, d, acc, logits, v, mode_pv, *, p_mask=None):
+def online_softmax_update(m, d, acc, logits, v, mode_pv, *, p_mask=None,
+                          split=limbs_lib.decompose):
     """One kv-block step of the running (max, denom, accum) softmax.
 
     m, d: (..., M); acc: (..., M, D); logits: (..., M, T_blk) f32 with
@@ -436,7 +443,7 @@ def online_softmax_update(m, d, acc, logits, v, mode_pv, *, p_mask=None):
         p = jnp.where(p_mask, p, 0.0)
     alpha = jnp.exp(m - m_new)
     d_new = d * alpha + jnp.sum(p, axis=-1)
-    acc_new = acc * alpha[..., None] + attn_pv(p, v, mode_pv)
+    acc_new = acc * alpha[..., None] + attn_pv(p, v, mode_pv, split=split)
     return m_new, d_new, acc_new
 
 

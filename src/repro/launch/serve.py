@@ -35,6 +35,7 @@ import jax
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core.policy import get_policy
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import transformer as T
 from repro.serve.engine import ServeEngine
 
@@ -81,6 +82,7 @@ def main():
                          "FaultPlan) injected deterministically — cell "
                          "crashes, poisoned decode steps, failed handoffs")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.backend:
         # one-shot process configuration (replaces REPRO_MP_BACKEND env)
@@ -134,19 +136,28 @@ def _build_stream(cfg, args, rng):
     return reqs
 
 
+def make_scheduler(cfg, params, *, slots: int, max_seq: int, policy,
+                   block_size: int = 16, n_blocks: int = 0):
+    """The engine and continuous scheduler that ``--scheduler`` serves
+    through (``chip_smoke.py`` serves through the same pair).  ``n_blocks`` 0 sizes
+    the paged pool at two full-length sequences per slot."""
+    from repro.serve.scheduler import ContinuousScheduler
+
+    eng = ServeEngine(cfg, params, max_batch=slots, max_seq=max_seq,
+                      policy=policy)
+    n_blocks = n_blocks or (
+        1 + slots * 2 * max(1, -(-max_seq // block_size)))
+    return ContinuousScheduler(eng, n_blocks=n_blocks, block_size=block_size)
+
+
 def _run_scheduler(cfg, params, args, rng):
     """Request-stream driver: Poisson arrivals through the continuous
     scheduler, each request optionally carrying its own precision mode."""
-    from repro.serve.scheduler import ContinuousScheduler
-
-    slots = min(args.requests, 8)
-    eng = ServeEngine(cfg, params, max_batch=slots, max_seq=args.max_seq,
-                      policy=get_policy(args.policy))
-    block_size = args.kv_block_size
-    n_blocks = args.kv_blocks or (
-        1 + slots * 2 * max(1, -(-(args.max_seq) // block_size)))
-    sched = ContinuousScheduler(eng, n_blocks=n_blocks,
-                                block_size=block_size)
+    sched = make_scheduler(cfg, params, slots=min(args.requests, 8),
+                           max_seq=args.max_seq,
+                           policy=get_policy(args.policy),
+                           block_size=args.kv_block_size,
+                           n_blocks=args.kv_blocks)
     done = sched.run(_build_stream(cfg, args, rng))
     for r in sorted(done, key=lambda r: r.rid):
         qos = r.mode or "engine-default"

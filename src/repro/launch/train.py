@@ -14,6 +14,7 @@ import jax
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core.policy import get_policy
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import configure_compile_cache
 from repro.optim import adamw
 from repro.train import trainer as trainer_lib
 
@@ -30,9 +31,11 @@ def main():
     ap.add_argument("--backend", default="",
                     help="mp_matmul dispatch backend (ref/pallas/"
                          "pallas_interpret/sharded); '' = context default")
-    ap.add_argument("--ckpt-dir", default="")
+    ap.add_argument("--ckpt-dir", default="",
+                    help="checkpoint directory ('' = no checkpoints)")
     ap.add_argument("--moment-dtype", default="float32")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.backend:
         # one-shot process configuration (replaces REPRO_MP_BACKEND env)
@@ -47,18 +50,28 @@ def main():
             f"--smoke on CPU, or launch on the production mesh (see "
             f"repro.launch.dryrun for the lowering proof).")
 
+    trainer, pipe = make_trainer(
+        cfg, batch=args.batch, seq=args.seq, steps=args.steps,
+        policy=get_policy(args.policy), ckpt_dir=args.ckpt_dir,
+        moment_dtype=args.moment_dtype)
+    state, history = trainer.run(pipe, num_steps=args.steps, log_every=10)
+    print(f"done: loss {history[0]:.4f} -> {history[-1]:.4f}")
+
+
+def make_trainer(cfg, *, batch: int, seq: int, steps: int, policy,
+                 ckpt_dir: str = "", moment_dtype: str = "float32"):
+    """The trainer and synthetic data pipeline the launcher runs
+    (``chip_smoke.py`` trains through the same pair).  An empty
+    ``ckpt_dir`` writes no checkpoints."""
     pipe = SyntheticLM(DataConfig(
-        vocab=cfg.vocab, seq_len=args.seq + 1, global_batch=args.batch,
+        vocab=cfg.vocab, seq_len=seq + 1, global_batch=batch,
         frontend=cfg.frontend, d_model=cfg.d_model,
         n_patches=cfg.n_patches))
     tcfg = trainer_lib.TrainerConfig(
-        opt=adamw.AdamWConfig(moment_dtype=args.moment_dtype),
-        total_steps=args.steps, warmup=max(2, args.steps // 20),
-        ckpt_dir=args.ckpt_dir or f"/tmp/repro_{args.arch}",
-        ckpt_every=max(10, args.steps // 5))
-    trainer = trainer_lib.Trainer(cfg, tcfg, policy=get_policy(args.policy))
-    state, history = trainer.run(pipe, num_steps=args.steps, log_every=10)
-    print(f"done: loss {history[0]:.4f} -> {history[-1]:.4f}")
+        opt=adamw.AdamWConfig(moment_dtype=moment_dtype),
+        total_steps=steps, warmup=max(2, steps // 20),
+        ckpt_dir=ckpt_dir, ckpt_every=max(10, steps // 5))
+    return trainer_lib.Trainer(cfg, tcfg, policy=policy), pipe
 
 
 if __name__ == "__main__":

@@ -17,8 +17,7 @@ re-traces once per policy, then swaps are free.
 Weight pre-limbing: decode is matmul-bound at tiny M (one token per slot),
 so the per-step VPU limb cascade over every *weight* dominates the paper's
 "truncate before multiply" cost.  The engine decomposes the dense-path
-weights ONCE per (policy, params) — via the Pallas decompose kernel
-(``kernels/ops.decompose_weights`` wrapping ``build_decompose_call``) at the
+weights ONCE per (policy, params) — with ``limbs.prelimb_weight`` at the
 policy's maximum limb count — and runs decode steps against
 :class:`~repro.core.limbs.PrelimbedWeight` operands, which dispatch routes
 through ``mp_matmul_prelimbed_weights`` (the kernel's ``prelimbed_b``
@@ -40,7 +39,7 @@ from repro.configs.base import ModelConfig
 from repro.core import context as context_lib
 from repro.core.formats import is_auto
 from repro.core.lanes import LaneCtx, LaneEnvelope, lane_scope
-from repro.core.limbs import PrelimbedWeight
+from repro.core.limbs import PrelimbedWeight, prelimb_weight
 from repro.core.policy import PrecisionPolicy
 from repro.models import transformer as T
 from repro.serve.kv_cache import PagedKVCache
@@ -69,28 +68,17 @@ def _policy_prelimb_limbs(policy: PrecisionPolicy) -> Optional[int]:
     return n
 
 
-def prelimb_dense_params(params, n_limbs: int, *, interpret: bool):
+def prelimb_dense_params(params, n_limbs: int):
     """Decompose the dense-path weight matrices of a transformer params tree
-    into :class:`PrelimbedWeight` limb stacks (one-time, per policy).
+    into :class:`PrelimbedWeight` limb stacks (one-time, per policy), with
+    the jnp cascade ``limbs.prelimb_weight`` on every backend.
 
-    Stacked per-layer weights (L, K, N) flatten their row dims through the
-    2-D Pallas decompose kernel (elementwise, so exact) and come back as
-    (L, n_limbs, K, N) — ``lax.scan`` then slices a layer's stack naturally.
-    Non-dict / absent groups pass through untouched.
+    Stacked per-layer weights (L, K, N) come back as (L, n_limbs, K, N) —
+    ``lax.scan`` then slices a layer's stack naturally.  Non-dict / absent
+    groups pass through untouched.
     """
-    from repro.kernels import ops  # deferred: imports pallas
-
     def leaf(w):
-        if w.ndim == 2:
-            return PrelimbedWeight(
-                ops.decompose_weights(w, n_limbs, interpret=interpret))
-        if w.ndim == 3:  # stacked per-layer (L, K, N)
-            L, K, N = w.shape
-            limbs = ops.decompose_weights(
-                w.reshape(L * K, N), n_limbs, interpret=interpret)
-            return PrelimbedWeight(
-                limbs.reshape(n_limbs, L, K, N).transpose(1, 0, 2, 3))
-        return w
+        return prelimb_weight(w, n_limbs) if w.ndim in (2, 3) else w
 
     out = dict(params)
     for stack_key in ("layers", "dense_layers"):
@@ -392,9 +380,7 @@ class ServeEngine:
             stale = [k for k in self._prelimb_cache if k[1] != id(self.params)]
             for k in stale:
                 del self._prelimb_cache[k]
-            interpret = jax.default_backend() == "cpu"
-            self._prelimb_cache[key] = prelimb_dense_params(
-                self.params, n, interpret=interpret)
+            self._prelimb_cache[key] = prelimb_dense_params(self.params, n)
         return self._prelimb_cache[key]
 
     def cache_stats(self) -> Dict[str, int]:

@@ -82,6 +82,28 @@ def test_dd_roundtrip_property(seed):
     assert np.max(np.abs(back - x64)) <= 2.0 ** -45 * np.max(np.abs(x64))
 
 
+def test_round_to_bf16_matches_convert_bitwise():
+    """The integer-op bf16 rounding the limb cascade uses (it survives XLA's
+    excess-precision rewrites on TPU) is the f32->bf16->f32 round trip, bit
+    for bit: ties to even, signed zeros, subnormals, overflow to inf, NaN."""
+    from repro.core.limbs import round_to_bf16
+
+    rng = np.random.default_rng(0)
+    x = np.concatenate([
+        (rng.standard_normal(4096)
+         * 10.0 ** rng.integers(-40, 38, 4096)).astype(np.float32),
+        np.array([0.0, -0.0, np.inf, -np.inf, np.nan, 3.4028235e38,
+                  -3.4028235e38, 1e-45, 2.3e-40, 1.0 + 2.0 ** -8,
+                  1.0 + 3 * 2.0 ** -8], np.float32)])
+    got = np.asarray(round_to_bf16(jnp.asarray(x)))
+    want = np.asarray(jnp.asarray(x).astype(jnp.bfloat16)
+                      .astype(jnp.float32))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
 def test_significant_limbs_detects_integers():
     ints = jnp.asarray(np.arange(-100, 100, dtype=np.float32).reshape(10, 20))
     assert int(significant_limbs(ints)) == 1

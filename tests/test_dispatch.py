@@ -229,6 +229,46 @@ def test_registry_routing_and_errors():
         unregister_backend("custom_test")
 
 
+def test_pallas_backend_needs_a_tpu():
+    """backend="pallas" means compiled Mosaic: on a host without a TPU it
+    raises instead of quietly running the interpreter, in dispatch and at
+    the engine's first step alike (CPU callers ask for pallas_interpret)."""
+    from repro.configs.registry import get_config
+    from repro.models import transformer as T
+    from repro.serve.engine import ServeEngine
+
+    a, b = jnp.ones((8, 128)), jnp.ones((128, 128))
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        dispatch(a, b, PrecisionMode.M16, backend="pallas")
+    cfg = get_config("paper-mpfp-100m", smoke=True)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, params, max_batch=2, max_seq=48,
+                      matmul_backend="pallas")
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        eng.generate([np.asarray([1, 2, 3], np.int32)], max_new=1)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The entry points' compile cache: $JAX_COMPILATION_CACHE_DIR when set
+    (JAX reads it itself, so the helper leaves the config alone), else the
+    fixed <repo>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import configure_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo_cache = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    try:
+        assert configure_compile_cache() == repo_cache
+        assert jax.config.jax_compilation_cache_dir == repo_cache
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_engine_pins_backend_end_to_end():
     """A ServeEngine built with matmul_backend="sharded" must decode through
     the multi-device path and produce the same tokens as the default engine

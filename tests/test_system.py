@@ -124,3 +124,23 @@ def test_auto_policy_end_to_end():
     logits, _, _ = T.forward(params, {"tokens": toks}, cfg,
                              get_policy("auto"))
     assert bool(jnp.all(jnp.isfinite(logits)))
+
+
+def test_chip_smoke_refuses_without_tpu(tmp_path):
+    """chip_smoke.py prints no result line and exits non-zero on a host
+    without a TPU."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    script = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
+    proc = subprocess.run([sys.executable, str(script)], cwd=tmp_path,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "needs a TPU" in proc.stderr

@@ -1,0 +1,132 @@
+"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e at
+the paper model's widths (``paper-mpfp-100m``: d_model 768, 12 heads of 64,
+d_ff 3072, vocab 32000), on a described ``v5e:2x2`` topology — no chip.
+
+Interpret mode accepts kernels that Mosaic refuses (grid / dimension-
+semantics mismatches, unaligned tiles, VMEM overruns); these compiles catch
+that class of fault before any chip time is spent.  Each test asserts the
+compiled program holds the Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a module fixture, never at import: only one
+process may load the TPU library at a time, so every test worker must
+collect the same tests and only the one given this file loads it.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.core.limbs import PrelimbedWeight
+from repro.kernels import mp_attention, ops
+
+CFG = get_config("paper-mpfp-100m")
+D, FF, V = CFG.d_model, CFG.d_ff, CFG.vocab
+H, DH = CFG.n_heads, CFG.resolved_head_dim
+TRAIN_TOKENS = 8 * 128      # batch 8 x seq 128
+DECODE_SLOTS = 8
+BLOCK, POOL_BLOCKS, TABLE_W = 16, 129, 8   # paged pool of the smoke server
+
+
+@pytest.fixture(scope="module")
+def topo(tmp_path_factory):
+    import os
+
+    from jax.experimental import topologies
+
+    # the TPU library logs under /tmp unless given a directory ("disabled"
+    # still leaves its driver log there): keep its logs with the test's
+    os.environ.setdefault("TPU_LOG_DIR",
+                          str(tmp_path_factory.mktemp("tpu_logs")))
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a persistent-cache entry compiled for a described chip cannot be read
+    # back without one: keep these compiles out of the cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, sharding, *shapes) -> str:
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+@pytest.mark.parametrize("mode", ["M8", "M23", "M36"])
+def test_fused_matmul(one_chip, mode):
+    _compiled_text(lambda a, b: ops.mp_matmul_pallas(a, b, mode), one_chip,
+                   ((TRAIN_TOKENS, D), F32), ((D, FF), F32))
+
+
+def test_fused_multi_swiglu(one_chip):
+    _compiled_text(
+        lambda x, wg, wu: ops.mp_fused_proj_pallas(x, (wg, wu), "M16",
+                                                   gate="swiglu"),
+        one_chip, ((TRAIN_TOKENS, D), F32), ((D, FF), F32), ((D, FF), F32))
+
+
+def test_prelimbed_b(one_chip):
+    _compiled_text(
+        lambda x, wl: ops.mp_matmul_prelimbed_weights(x, wl, "M23"),
+        one_chip, ((DECODE_SLOTS, 1, D), F32), ((3, D, FF), BF16))
+
+
+def test_mixed_prelimbed(one_chip):
+    _compiled_text(
+        lambda x, wl, ln, lo: ops.mp_mixed_matmul_pallas(
+            x, PrelimbedWeight(wl), "M23", ln, lo),
+        one_chip, ((DECODE_SLOTS, 1, D), F32), ((3, D, FF), BF16),
+        ((DECODE_SLOTS,), I32), ((DECODE_SLOTS,), I32))
+
+
+def test_decompose(one_chip):
+    _compiled_text(lambda w: ops.decompose_weights(w, 3), one_chip,
+                   ((D, V), F32))
+
+
+@pytest.mark.parametrize("batch,seq", [(1, 64), (8, 128)],
+                         ids=["prefill", "train"])
+def test_flash_attention(one_chip, batch, seq):
+    qkv = ((batch, seq, H, DH), F32)
+    _compiled_text(
+        lambda q, k, v: mp_attention.mp_attention_pallas(q, k, v, "M16",
+                                                         "M23"),
+        one_chip, qkv, qkv, qkv)
+
+
+POOL = ((POOL_BLOCKS, BLOCK, H, DH), F32)
+TABLE = ((DECODE_SLOTS, TABLE_W), I32)
+SLOTS = ((DECODE_SLOTS,), I32)
+
+
+def test_paged_attention(one_chip):
+    _compiled_text(
+        lambda q, kp, vp, t, ln: mp_attention.mp_paged_attention_pallas(
+            q, kp, vp, t, ln, "M23"),
+        one_chip, ((DECODE_SLOTS, H, DH), F32), POOL, POOL, TABLE, SLOTS)
+
+
+def test_mixed_paged_attention(one_chip):
+    _compiled_text(
+        lambda q, kp, vp, t, ln, a, b, c, d:
+            mp_attention.mp_mixed_paged_attention_pallas(
+                q, kp, vp, t, ln, "M23", "M23", a, b, c, d),
+        one_chip, ((DECODE_SLOTS, H, DH), F32), POOL, POOL, TABLE, SLOTS,
+        SLOTS, SLOTS, SLOTS, SLOTS)
