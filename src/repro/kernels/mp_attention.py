@@ -225,6 +225,7 @@ def mp_attention_pallas(
             ("parallel", "parallel", "arbitrary")),
         cost_estimate=cost,
         interpret=interpret,
+        name="mp_flash_attention",
     )
     out = call(qf, kf, vf)
     out = out[:, :S, :Dh].reshape(B, H, S, Dh)
@@ -343,6 +344,7 @@ def mp_paged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), out_dtype),
         compiler_params=_compiler_params(PAGED_SEMANTICS),
         interpret=interpret,
+        name="mp_paged_attention",
     )
     return call(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
                 q.astype(jnp.float32), k_pool, v_pool)
@@ -477,6 +479,7 @@ def mp_mixed_paged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), out_dtype),
         compiler_params=_compiler_params(PAGED_SEMANTICS),
         interpret=interpret,
+        name="mp_mixed_paged_attention",
     )
     return call(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
                 lanes, q.astype(jnp.float32), k_pool, v_pool)

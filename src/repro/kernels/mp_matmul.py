@@ -367,6 +367,7 @@ def build_fused_multi_call(
         compiler_params=_compiler_params(MATMUL_SEMANTICS),
         cost_estimate=cost,
         interpret=interpret,
+        name="mp_matmul_fused_multi",
     )
 
 
@@ -436,6 +437,7 @@ def build_fused_call(
         compiler_params=_compiler_params(MATMUL_SEMANTICS),
         cost_estimate=cost,
         interpret=interpret,
+        name="mp_matmul_fused",
     )
 
 
@@ -473,6 +475,7 @@ def build_prelimbed_call(
         scratch_shapes=[pltpu.VMEM((n_orders, bm, bn), jnp.float32)],
         compiler_params=_compiler_params(MATMUL_SEMANTICS),
         interpret=interpret,
+        name="mp_matmul_prelimbed_both" if both else "mp_matmul_prelimbed",
     )
 
 
@@ -507,6 +510,7 @@ def build_mixed_prelimbed_call(
         scratch_shapes=[pltpu.VMEM((n_orders, bm, bn), jnp.float32)],
         compiler_params=_compiler_params(MATMUL_SEMANTICS),
         interpret=interpret,
+        name="mp_matmul_mixed_prelimbed",
     )
 
 
@@ -534,4 +538,5 @@ def build_decompose_call(
         out_shape=jax.ShapeDtypeStruct((n_limbs, R, C), jnp.bfloat16),
         compiler_params=_compiler_params(("parallel", "parallel")),
         interpret=interpret,
+        name="mp_limb_decompose",
     )

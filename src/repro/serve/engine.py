@@ -268,20 +268,25 @@ class ServeEngine:
     # compiled executables without bound
     MAX_POLICY_CACHE = 8
 
-    def _counted_trace(self, fn):
+    def _counted_trace(self, fn, name: str):
         """Bump ``trace_events`` each time jax (re)traces ``fn`` — the body
         runs once per trace, so the counter is a trace spy, not a call
-        counter (compiled executions never re-enter the Python body)."""
-        def wrapped(*args, **kwargs):
+        counter (compiled executions never re-enter the Python body).  The
+        wrapper carries ``name``, which ``jax.jit`` turns into the program's
+        name (``jit_<name>``) on the device trace."""
+        def counted(*args, **kwargs):
             self.trace_events += 1
             return fn(*args, **kwargs)
 
-        return wrapped
+        counted.__name__ = counted.__qualname__ = name
+        return counted
 
     def _cached_steps(self, cache: Dict, key, factories: Tuple) -> Tuple:
         """Shared LRU discipline for every jit'd step cache (keyed by policy
         or lane envelope): touch on hit, evict oldest past MAX_POLICY_CACHE,
-        trace (with the engine's backend pinned) on miss."""
+        trace (with the engine's backend pinned) on miss.  Each step is
+        named after its factory: ``make_paged_decode_step`` gives
+        ``paged_decode`` (program ``jit_paged_decode``)."""
         if key in cache:
             cache[key] = cache.pop(key)  # LRU touch
             self.step_cache_hits += 1
@@ -294,7 +299,8 @@ class ServeEngine:
             cache[key] = tuple(
                 jax.jit(self._counted_trace(
                     pin_backend(make(self.cfg, key, self.mesh),
-                                self.matmul_backend)))
+                                self.matmul_backend),
+                    make.__name__.removeprefix("make_").removesuffix("_step")))
                 for make in factories)
         return cache[key]
 

@@ -36,6 +36,13 @@ Keeping these here (engine-agnostic, pool-explicit) is what lets a
 disaggregated prefill engine and a decode engine on a *different* pool run
 the exact jit'd steps the single-engine scheduler runs — the KV-handoff
 bit-parity guarantee (tests/test_fleet.py) falls out of the sharing.
+
+Host spans: the prefill and decode primitives open
+``jax.profiler.TraceAnnotation`` spans (``serve.prefill`` with its
+``.launch``/``.sync`` children; ``serve.decode`` with ``.prep``, ``.launch``,
+``.sync``, ``.finish``), one per launch and never per row.  They record only
+while a profiler collects, on the device trace's clock, so a trace can say
+which host code sits in each device-idle gap; both control loops get them.
 """
 from __future__ import annotations
 
@@ -45,6 +52,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import context as context_lib
 from repro.core.formats import (
@@ -336,25 +344,28 @@ def prefill_request(engine, pool: PagedKVPool, req: ScheduledRequest) -> int:
     the returned token (the already-emitted ``out[-1]`` stays the decode
     input; under an unchanged mode the two are bit-identical anyway, under
     an escalated mode the emitted history is immutable)."""
-    policy = resolve_request(req, engine.policy)
-    prefill_fn, _ = engine.paged_steps_for(policy)
     seq = prefill_tokens(req)
     n = len(seq)
     s_pad = pow2_at_least(n)
-    tokens = np.zeros((1, s_pad), np.int32)
-    tokens[0, :n] = seq
-    table = pool.table_row(req.blocks)[None, :table_width(pool, [req])]
-    lengths = np.zeros((1,), np.int32)
-    logits, _stat, new_k, new_v = prefill_fn(
-        engine.params, pool.k, pool.v,
-        jnp.asarray(table), jnp.asarray(lengths), jnp.asarray(tokens),
-        np.int32(n - 1))
-    pool.update(new_k, new_v)
-    req.length = n
-    now = time.perf_counter()
-    if req.t_first < 0:
-        req.t_first = now
-    return int(jnp.argmax(logits[0, 0, :]))
+    with TraceAnnotation("serve.prefill"):
+        policy = resolve_request(req, engine.policy)
+        prefill_fn, _ = engine.paged_steps_for(policy)
+        tokens = np.zeros((1, s_pad), np.int32)
+        tokens[0, :n] = seq
+        table = pool.table_row(req.blocks)[None, :table_width(pool, [req])]
+        lengths = np.zeros((1,), np.int32)
+        with TraceAnnotation("serve.prefill.launch"):
+            logits, _stat, new_k, new_v = prefill_fn(
+                engine.params, pool.k, pool.v,
+                jnp.asarray(table), jnp.asarray(lengths),
+                jnp.asarray(tokens), np.int32(n - 1))
+        pool.update(new_k, new_v)
+        req.length = n
+        now = time.perf_counter()
+        if req.t_first < 0:
+            req.t_first = now
+        with TraceAnnotation("serve.prefill.sync"):
+            return int(jnp.argmax(logits[0, 0, :]))
 
 
 def bucket_by_policy(reqs: Sequence[ScheduledRequest],
@@ -430,17 +441,23 @@ def decode_bucket_step(engine, pool: PagedKVPool,
                 engine, pool, part, max_slots=cap, guard=guard,
                 injector=injector, cell_id=cell_id), reqs, cap)
     mb = min(pow2_at_least(len(reqs)), cap)
-    table, lengths, tokens, w = _micro_batch(pool, reqs, mb)
-    policy = resolve_request(reqs[0], engine.policy)
-    _, decode_fn = engine.paged_steps_for(policy)
-    params = engine._decode_params_for(policy)
-    logits, stat, new_k, new_v = decode_fn(
-        params, pool.k, pool.v, jnp.asarray(table),
-        jnp.asarray(lengths), jnp.asarray(tokens))
-    pool.update(new_k, new_v)
-    toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
-    ok = guard_check(np.asarray(stat)[: len(reqs)], policy, guard)
-    _finish_decode_rows(reqs, ok, injector, cell_id)
+    with TraceAnnotation("serve.decode"):
+        with TraceAnnotation("serve.decode.prep"):
+            table, lengths, tokens, w = _micro_batch(pool, reqs, mb)
+            policy = resolve_request(reqs[0], engine.policy)
+            _, decode_fn = engine.paged_steps_for(policy)
+            params = engine._decode_params_for(policy)
+        with TraceAnnotation("serve.decode.launch"):
+            logits, stat, new_k, new_v = decode_fn(
+                params, pool.k, pool.v, jnp.asarray(table),
+                jnp.asarray(lengths), jnp.asarray(tokens))
+        pool.update(new_k, new_v)
+        with TraceAnnotation("serve.decode.sync"):
+            toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+            stat_np = np.asarray(stat)[: len(reqs)]
+        with TraceAnnotation("serve.decode.finish"):
+            ok = guard_check(stat_np, policy, guard)
+            _finish_decode_rows(reqs, ok, injector, cell_id)
     return toks[: len(reqs)], ok
 
 
@@ -518,23 +535,28 @@ def decode_mixed_step(engine, pool: PagedKVPool,
                 engine, pool, part, max_slots=cap, guard=guard,
                 injector=injector, cell_id=cell_id), reqs, cap)
     mb = min(pow2_at_least(len(reqs)), cap)
-    table, lengths, tokens, w = _micro_batch(pool, reqs, mb)
-    policies = [resolve_request(r, engine.policy) for r in reqs]
-    env = lanes_lib.envelope_of(policies)
-    lane_n, lane_ord = lanes_lib.lane_tables(policies, mb)
-    decode_fn = engine.mixed_decode_step_for(env)
-    params = engine._decode_params_for_limbs(env.max_limbs)
-    logits, stat, new_k, new_v = decode_fn(
-        params, pool.k, pool.v, jnp.asarray(table),
-        jnp.asarray(lengths), jnp.asarray(tokens),
-        jnp.asarray(lane_n), jnp.asarray(lane_ord))
-    pool.update(new_k, new_v)
-    toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
-    stat_np = np.asarray(stat)[: len(reqs)]
-    ok = np.ones(len(reqs), bool)
-    for i, (r, pol) in enumerate(zip(reqs, policies)):
-        ok[i] = bool(guard_check(stat_np[i:i + 1], pol, guard)[0])
-    _finish_decode_rows(reqs, ok, injector, cell_id)
+    with TraceAnnotation("serve.decode"):
+        with TraceAnnotation("serve.decode.prep"):
+            table, lengths, tokens, w = _micro_batch(pool, reqs, mb)
+            policies = [resolve_request(r, engine.policy) for r in reqs]
+            env = lanes_lib.envelope_of(policies)
+            lane_n, lane_ord = lanes_lib.lane_tables(policies, mb)
+            decode_fn = engine.mixed_decode_step_for(env)
+            params = engine._decode_params_for_limbs(env.max_limbs)
+        with TraceAnnotation("serve.decode.launch"):
+            logits, stat, new_k, new_v = decode_fn(
+                params, pool.k, pool.v, jnp.asarray(table),
+                jnp.asarray(lengths), jnp.asarray(tokens),
+                jnp.asarray(lane_n), jnp.asarray(lane_ord))
+        pool.update(new_k, new_v)
+        with TraceAnnotation("serve.decode.sync"):
+            toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+            stat_np = np.asarray(stat)[: len(reqs)]
+        with TraceAnnotation("serve.decode.finish"):
+            ok = np.ones(len(reqs), bool)
+            for i, (r, pol) in enumerate(zip(reqs, policies)):
+                ok[i] = bool(guard_check(stat_np[i:i + 1], pol, guard)[0])
+            _finish_decode_rows(reqs, ok, injector, cell_id)
     return toks[: len(reqs)], ok
 
 

@@ -51,6 +51,7 @@ from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.serve import primitives as prim
 from repro.serve.engine import ServeEngine
@@ -155,28 +156,32 @@ class ContinuousScheduler:
         token is discarded — the streamed history is immutable, and under an
         escalated mode the re-run token could differ — and decode resumes
         consuming ``out[-1]``.
+
+        The ``serve.admit`` span holds one ``serve.prefill`` per request
+        admitted.
         """
         admitted = 0
-        while self._queue:
-            req = self._queue[0]
-            slot = self._free_slot()
-            if slot is None:
-                break
-            if not prim.try_reserve(self.pool, req):
-                break  # reservation not available yet; eviction will free it
-            self._queue.popleft()
-            req.slot = slot
-            req.state = "running"
-            req.admitted_step = self.steps
-            self._slots[slot] = req
-            resumed = bool(req.out)
-            tok = prim.prefill_request(self.engine, self.pool, req)
-            self.prefills += 1
-            if resumed:
-                req.next_token = req.out[-1]
-            else:
-                self._push_token(req, tok)
-            admitted += 1
+        with TraceAnnotation("serve.admit"):
+            while self._queue:
+                req = self._queue[0]
+                slot = self._free_slot()
+                if slot is None:
+                    break
+                if not prim.try_reserve(self.pool, req):
+                    break  # not available yet; eviction will free it
+                self._queue.popleft()
+                req.slot = slot
+                req.state = "running"
+                req.admitted_step = self.steps
+                self._slots[slot] = req
+                resumed = bool(req.out)
+                tok = prim.prefill_request(self.engine, self.pool, req)
+                self.prefills += 1
+                if resumed:
+                    req.next_token = req.out[-1]
+                else:
+                    self._push_token(req, tok)
+                admitted += 1
         return admitted
 
     # ---- decode ------------------------------------------------------------
@@ -269,30 +274,35 @@ class ContinuousScheduler:
         homogeneous set on the legacy per-policy step, a heterogeneous set
         on the partitioned-lane mixed step (per-slot lane tables inside one
         jit'd launch).  Only AUTO-policy requests still bucket per policy.
-        Returns True if any work was done."""
-        if self.injector is not None:
-            self.injector.begin_tick(self.steps)
-        self._sweep_deadlines()
-        admitted = self._admit()
-        active = [r for r in self._slots if r is not None]
-        plan = prim.decode_tick_plan(active, self.engine.policy)
-        cap = prim.pow2_at_most(self.max_slots)
-        for kind, reqs in plan:
-            step_fn = (prim.decode_mixed_step if kind == "mixed"
-                       else prim.decode_bucket_step)
-            toks, ok = step_fn(
-                self.engine, self.pool, reqs, max_slots=self.max_slots,
-                guard=self.guard, injector=self.injector, cell_id=0)
-            self.decode_launches += -(-len(reqs) // cap)
-            self.decode_token_slots += len(reqs)
-            for req, tok, good in zip(list(reqs), toks, ok):
-                if good:
-                    self._push_token(req, int(tok))
-                else:
-                    self._trip(req)
-        if plan:
-            self.decode_ticks += 1
-            self.steps += 1
+        Returns True if any work was done.
+
+        The tick is one ``serve.tick`` host span; each launch's tokens are
+        handed out (and finished requests evicted) inside ``serve.emit``."""
+        with TraceAnnotation("serve.tick"):
+            if self.injector is not None:
+                self.injector.begin_tick(self.steps)
+            self._sweep_deadlines()
+            admitted = self._admit()
+            active = [r for r in self._slots if r is not None]
+            plan = prim.decode_tick_plan(active, self.engine.policy)
+            cap = prim.pow2_at_most(self.max_slots)
+            for kind, reqs in plan:
+                step_fn = (prim.decode_mixed_step if kind == "mixed"
+                           else prim.decode_bucket_step)
+                toks, ok = step_fn(
+                    self.engine, self.pool, reqs, max_slots=self.max_slots,
+                    guard=self.guard, injector=self.injector, cell_id=0)
+                self.decode_launches += -(-len(reqs) // cap)
+                self.decode_token_slots += len(reqs)
+                with TraceAnnotation("serve.emit"):
+                    for req, tok, good in zip(list(reqs), toks, ok):
+                        if good:
+                            self._push_token(req, int(tok))
+                        else:
+                            self._trip(req)
+            if plan:
+                self.decode_ticks += 1
+                self.steps += 1
         return bool(admitted or plan)
 
     # ---- drivers -----------------------------------------------------------

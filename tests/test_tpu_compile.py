@@ -5,12 +5,15 @@ d_ff 3072, vocab 32000), on a described ``v5e:2x2`` topology — no chip.
 Interpret mode accepts kernels that Mosaic refuses (grid / dimension-
 semantics mismatches, unaligned tiles, VMEM overruns); these compiles catch
 that class of fault before any chip time is spent.  Each test asserts the
-compiled program holds the Mosaic kernel (``tpu_custom_call``).
+compiled program holds the Mosaic kernel (``tpu_custom_call``), named by
+its ``pallas_call`` ``name=`` (the name the device trace's op text shows).
 
 The topology is described inside a module fixture, never at import: only one
 process may load the TPU library at a time, so every test worker must
 collect the same tests and only the one given this file loads it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -59,10 +62,11 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _compiled_text(fn, sharding, *shapes) -> str:
+def _compiled_text(fn, sharding, *shapes, kernel: str) -> str:
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = .* custom-call\(", text), kernel
     return text
 
 
@@ -72,20 +76,23 @@ F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 @pytest.mark.parametrize("mode", ["M8", "M23", "M36"])
 def test_fused_matmul(one_chip, mode):
     _compiled_text(lambda a, b: ops.mp_matmul_pallas(a, b, mode), one_chip,
-                   ((TRAIN_TOKENS, D), F32), ((D, FF), F32))
+                   ((TRAIN_TOKENS, D), F32), ((D, FF), F32),
+                   kernel="mp_matmul_fused")
 
 
 def test_fused_multi_swiglu(one_chip):
     _compiled_text(
         lambda x, wg, wu: ops.mp_fused_proj_pallas(x, (wg, wu), "M16",
                                                    gate="swiglu"),
-        one_chip, ((TRAIN_TOKENS, D), F32), ((D, FF), F32), ((D, FF), F32))
+        one_chip, ((TRAIN_TOKENS, D), F32), ((D, FF), F32), ((D, FF), F32),
+        kernel="mp_matmul_fused_multi")
 
 
 def test_prelimbed_b(one_chip):
     _compiled_text(
         lambda x, wl: ops.mp_matmul_prelimbed_weights(x, wl, "M23"),
-        one_chip, ((DECODE_SLOTS, 1, D), F32), ((3, D, FF), BF16))
+        one_chip, ((DECODE_SLOTS, 1, D), F32), ((3, D, FF), BF16),
+        kernel="mp_matmul_prelimbed")
 
 
 def test_mixed_prelimbed(one_chip):
@@ -93,12 +100,13 @@ def test_mixed_prelimbed(one_chip):
         lambda x, wl, ln, lo: ops.mp_mixed_matmul_pallas(
             x, PrelimbedWeight(wl), "M23", ln, lo),
         one_chip, ((DECODE_SLOTS, 1, D), F32), ((3, D, FF), BF16),
-        ((DECODE_SLOTS,), I32), ((DECODE_SLOTS,), I32))
+        ((DECODE_SLOTS,), I32), ((DECODE_SLOTS,), I32),
+        kernel="mp_matmul_mixed_prelimbed")
 
 
 def test_decompose(one_chip):
     _compiled_text(lambda w: ops.decompose_weights(w, 3), one_chip,
-                   ((D, V), F32))
+                   ((D, V), F32), kernel="mp_limb_decompose")
 
 
 @pytest.mark.parametrize("batch,seq", [(1, 64), (8, 128)],
@@ -108,7 +116,7 @@ def test_flash_attention(one_chip, batch, seq):
     _compiled_text(
         lambda q, k, v: mp_attention.mp_attention_pallas(q, k, v, "M16",
                                                          "M23"),
-        one_chip, qkv, qkv, qkv)
+        one_chip, qkv, qkv, qkv, kernel="mp_flash_attention")
 
 
 POOL = ((POOL_BLOCKS, BLOCK, H, DH), F32)
@@ -120,7 +128,8 @@ def test_paged_attention(one_chip):
     _compiled_text(
         lambda q, kp, vp, t, ln: mp_attention.mp_paged_attention_pallas(
             q, kp, vp, t, ln, "M23"),
-        one_chip, ((DECODE_SLOTS, H, DH), F32), POOL, POOL, TABLE, SLOTS)
+        one_chip, ((DECODE_SLOTS, H, DH), F32), POOL, POOL, TABLE, SLOTS,
+        kernel="mp_paged_attention")
 
 
 def test_mixed_paged_attention(one_chip):
@@ -129,4 +138,4 @@ def test_mixed_paged_attention(one_chip):
             mp_attention.mp_mixed_paged_attention_pallas(
                 q, kp, vp, t, ln, "M23", "M23", a, b, c, d),
         one_chip, ((DECODE_SLOTS, H, DH), F32), POOL, POOL, TABLE, SLOTS,
-        SLOTS, SLOTS, SLOTS, SLOTS)
+        SLOTS, SLOTS, SLOTS, SLOTS, kernel="mp_mixed_paged_attention")
