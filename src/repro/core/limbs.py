@@ -12,7 +12,7 @@ extracts up to 7 limbs from it.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Sequence
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,12 +34,19 @@ class DD(NamedTuple):
         return self.hi.dtype
 
 
-class PrelimbedWeight(NamedTuple):
+@jax.tree_util.register_pytree_node_class
+class PrelimbedWeight:
     """A weight operand carried as its pre-extracted bf16 limb stack.
 
-    ``limbs`` has shape (..., L, K, N): the last three dims are the limb
-    stack of one (K, N) matrix; leading dims (stacked per-layer weights) ride
-    along so ``lax.scan`` slices a layer's (L, K, N) stack out naturally.
+    ``limbs`` has shape (..., L, K', N'): the last three dims are the limb
+    stack of one matrix whose logical shape is ``kn`` = (K, N), stored
+    zero-padded to (K', N') >= (K, N) (the serving engine stores each
+    stack at the kernel's block multiples, so a decode step pads nothing;
+    zero limbs add nothing to any sum).  ``kn`` is static pytree metadata,
+    not a leaf.  Leading dims, where a stack of per-layer weights was
+    prelimbed whole, ride along; the serving engine instead keeps one
+    stack per layer (``serve/engine.prelimb_dense_params``), since a scan
+    would copy each layer's slice out of the stack on every step.
     Serving decomposes each weight ONCE per (policy, params) — decode steps
     then skip the per-step B-limb VPU cascade entirely (the kernel's
     ``prelimbed_b`` variant).  Inference-only, like :class:`DD`: no VJP
@@ -47,12 +54,29 @@ class PrelimbedWeight(NamedTuple):
     stored precision (missing limbs are zero).
     """
 
-    limbs: jax.Array  # (..., L, K, N) bf16
+    __slots__ = ("limbs", "kn")
+
+    def __init__(self, limbs: jax.Array, kn=None):
+        self.limbs = limbs  # (..., L, K', N') bf16
+        self.kn = tuple(int(d) for d in (limbs.shape[-2:] if kn is None
+                                         else kn))
+
+    def tree_flatten(self):
+        return (self.limbs,), self.kn
+
+    @classmethod
+    def tree_unflatten(cls, kn, children):
+        obj = object.__new__(cls)
+        obj.limbs, obj.kn = children[0], kn
+        return obj
+
+    def __repr__(self):
+        return f"PrelimbedWeight(limbs={self.limbs!r}, kn={self.kn})"
 
     @property
     def shape(self):
         """Shape of the weight *value* the limb stack represents."""
-        return self.limbs.shape[:-3] + self.limbs.shape[-2:]
+        return tuple(self.limbs.shape[:-3]) + self.kn
 
     @property
     def ndim(self) -> int:
@@ -62,15 +86,32 @@ class PrelimbedWeight(NamedTuple):
     def n_limbs(self) -> int:
         return self.limbs.shape[-3]
 
+    def logical_limbs(self) -> jax.Array:
+        """The limb stack cut to the logical (K, N) (no op when unpadded)."""
+        if tuple(self.limbs.shape[-2:]) == self.kn:
+            return self.limbs
+        return self.limbs[..., : self.kn[0], : self.kn[1]]
 
-def prelimb_weight(w: jax.Array, n_limbs: int) -> PrelimbedWeight:
+
+def prelimb_weight(w: jax.Array, n_limbs: int,
+                   padded: Optional[Tuple[int, int]] = None
+                   ) -> PrelimbedWeight:
     """Pure-jnp prelimb of a (..., K, N) weight -> (..., n_limbs, K, N)
     (what the serving engine runs once per policy; the Pallas decompose
-    kernel, kernels/ops.decompose_weights, gives the same limbs)."""
+    kernel, kernels/ops.decompose_weights, gives the same limbs).
+
+    ``padded`` (K', N') stores the stack zero-padded to that shape: the
+    weight is padded before it is decomposed, so no unpadded stack is ever
+    held beside the padded one."""
+    kn = w.shape[-2:]
+    if padded is not None and tuple(padded) != tuple(kn):
+        pad = [(0, 0)] * (w.ndim - 2) + [(0, padded[0] - kn[0]),
+                                         (0, padded[1] - kn[1])]
+        w = jnp.pad(w, pad)
     stacked = decompose(w, n_limbs)  # (L, ..., K, N)
     order = tuple(range(1, stacked.ndim - 2)) + (0, stacked.ndim - 2,
                                                  stacked.ndim - 1)
-    return PrelimbedWeight(jnp.transpose(stacked, order))
+    return PrelimbedWeight(jnp.transpose(stacked, order), kn)
 
 
 def dd_from_f64(x64: np.ndarray) -> DD:

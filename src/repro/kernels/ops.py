@@ -133,7 +133,7 @@ def mp_matmul_pallas(
     if isinstance(b, PrelimbedWeight) and not isinstance(a, (DD, PrelimbedWeight)):
         assert b.ndim == 2, "prelimbed weights must be 2-D per matmul"
         return mp_matmul_prelimbed_weights(
-            a, b.limbs, mode, out_dtype=out_dtype, interpret=interpret,
+            a, b, mode, out_dtype=out_dtype, interpret=interpret,
             bm=bm, bk=bk, bn=bn)
     if isinstance(a, DD) or isinstance(b, DD):
         assert (a.hi.ndim if isinstance(a, DD) else a.ndim) == 2, (
@@ -240,9 +240,36 @@ def mp_fused_proj_pallas(
     return tuple(out[t].reshape(lead + (N,)) for t in range(n_out))
 
 
+def prelimbed_storage_shape(K: int, N: int) -> Tuple[int, int]:
+    """The (K', N') a prelimbed (K, N) weight is stored at: the multiples
+    of the wrapper's default K and N blocks, which do not depend on M or
+    the mode (``_clamp_vmem`` only halves bk, to a divisor of K').  A limb
+    stack stored at this shape reaches the kernel without a pad."""
+    _, bk, bn = _pick_blocks(8, K, N, None, None, None)
+    return _round_up(K, bk), _round_up(N, bn)
+
+
+def _weight_limbs(w: Union[jax.Array, PrelimbedWeight], n_limbs: int
+                  ) -> Tuple[jax.Array, int, int]:
+    """A prelimbed weight's stored stack at ``n_limbs`` limbs (missing
+    limbs zero, extra ones dropped) and its logical (K, N).  A bare
+    (L, K, N) limb array is its own logical shape."""
+    if isinstance(w, PrelimbedWeight):
+        assert w.ndim == 2, "prelimbed weights must be 2-D per matmul"
+        w_limbs, (K, N) = w.limbs, w.kn
+    else:
+        w_limbs, (K, N) = w, w.shape[1:]
+    if w_limbs.shape[0] < n_limbs:
+        w_limbs = jnp.concatenate([
+            w_limbs,
+            jnp.zeros((n_limbs - w_limbs.shape[0],) + w_limbs.shape[1:],
+                      jnp.bfloat16)], axis=0)
+    return w_limbs[:n_limbs], K, N
+
+
 def mp_matmul_prelimbed_weights(
     x: jax.Array,
-    w_limbs: jax.Array,
+    w: Union[jax.Array, PrelimbedWeight],
     mode: FormatLike,
     *,
     out_dtype=jnp.float32,
@@ -254,26 +281,24 @@ def mp_matmul_prelimbed_weights(
     """Serving fast path: weights decomposed once (``decompose_weights``),
     activations limbed on the fly inside the kernel.  x (..., K) @ W (K, N).
 
-    A mode needing more limbs than were stored computes at the stored
-    precision: the missing limbs are zero by construction."""
+    ``w`` is a :class:`PrelimbedWeight` (its stack may be stored padded,
+    ``prelimbed_storage_shape``: then only the activations are padded) or a
+    bare (L, K, N) limb stack.  A mode needing more limbs than were stored
+    computes at the stored precision: the missing limbs are zero by
+    construction."""
     s = resolve(mode)
-    if w_limbs.shape[0] < s.n_limbs:
-        w_limbs = jnp.concatenate([
-            w_limbs,
-            jnp.zeros((s.n_limbs - w_limbs.shape[0],) + w_limbs.shape[1:],
-                      jnp.bfloat16)], axis=0)
-    w_limbs = w_limbs[: s.n_limbs]
+    w_limbs, K, N = _weight_limbs(w, s.n_limbs)
     lead = x.shape[:-1]
     a = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
-    M, K = a.shape
-    _, K2, N = w_limbs.shape
-    assert K == K2
-    bm_, bk_, bn_ = _pick_blocks(M, K, N, bm, bk, bn)
+    M = a.shape[0]
+    assert a.shape[1] == K, (a.shape, K)
+    _, Ks, Ns = w_limbs.shape
+    bm_, bk_, bn_ = _pick_blocks(M, Ks, Ns, bm, bk, bn)
     bm_, bk_, bn_ = _clamp_vmem(mode, bm_, bk_, bn_, out_dtype,
                                 variant="prelimbed_b")
-    Mp, Kp, Np = _round_up(M, bm_), _round_up(K, bk_), _round_up(N, bn_)
+    Mp, Kp, Np = _round_up(M, bm_), _round_up(Ks, bk_), _round_up(Ns, bn_)
     a = _pad2(a, Mp, Kp)
-    w_limbs = jnp.pad(w_limbs, [(0, 0), (0, Kp - K), (0, Np - N)])
+    w_limbs = _pad2(w_limbs, Kp, Np)
     call = kern.build_prelimbed_call(
         Mp, Kp, Np, mode, bm=bm_, bk=bk_, bn=bn_, out_dtype=out_dtype,
         interpret=interpret, both=False,
@@ -309,32 +334,24 @@ def mp_mixed_matmul_pallas(
     like any block-size change.
     """
     s = resolve(env)
-    if isinstance(w, PrelimbedWeight):
-        assert w.ndim == 2, "prelimbed weights must be 2-D per matmul"
-        w_limbs = w.limbs
-    else:
+    if not isinstance(w, PrelimbedWeight):
         assert w.ndim == 2, "mixed matmul weights must be 2-D"
-        w_limbs = decompose_weights(w.astype(jnp.float32), s.n_limbs,
-                                    interpret=interpret)
-    if w_limbs.shape[0] < s.n_limbs:
-        w_limbs = jnp.concatenate([
-            w_limbs,
-            jnp.zeros((s.n_limbs - w_limbs.shape[0],) + w_limbs.shape[1:],
-                      jnp.bfloat16)], axis=0)
-    w_limbs = w_limbs[: s.n_limbs]
+        w = decompose_weights(w.astype(jnp.float32), s.n_limbs,
+                              interpret=interpret)
+    w_limbs, K, N = _weight_limbs(w, s.n_limbs)
     lead = x.shape[:-1]
     a = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
-    M, K = a.shape
-    _, K2, N = w_limbs.shape
-    assert K == K2
+    M = a.shape[0]
+    assert a.shape[1] == K, (a.shape, K)
+    _, Ks, Ns = w_limbs.shape
     lane_n = jnp.broadcast_to(lane_n.reshape(-1), (M,)).astype(jnp.int32)
     lane_ord = jnp.broadcast_to(lane_ord.reshape(-1), (M,)).astype(jnp.int32)
-    bm_, bk_, bn_ = _pick_blocks(M, K, N, bm, bk, bn)
+    bm_, bk_, bn_ = _pick_blocks(M, Ks, Ns, bm, bk, bn)
     bm_, bk_, bn_ = _clamp_vmem(s, bm_, bk_, bn_, out_dtype,
                                 variant="prelimbed_b")
-    Mp, Kp, Np = _round_up(M, bm_), _round_up(K, bk_), _round_up(N, bn_)
+    Mp, Kp, Np = _round_up(M, bm_), _round_up(Ks, bk_), _round_up(Ns, bn_)
     a = _pad2(a, Mp, Kp)
-    w_limbs = jnp.pad(w_limbs, [(0, 0), (0, Kp - K), (0, Np - N)])
+    w_limbs = _pad2(w_limbs, Kp, Np)
     # pad rows take the cheapest lane (1 limb, order 0); their outputs are
     # sliced off.  Lane values broadcast across a 128-wide lane dim so the
     # int32 operand tiles on TPU-aligned (·, 128) blocks.

@@ -28,13 +28,15 @@ def _limbs_of(x: Operand, n_limbs: int) -> jax.Array:
     if isinstance(x, DD):
         return limbs_lib.decompose_dd(x, n_limbs)
     if isinstance(x, PrelimbedWeight):
-        # limbs already extracted (serving path); missing ones are zero —
-        # the value simply carries no bits beyond its stored precision
-        have = x.limbs.shape[0]
+        # limbs already extracted (serving path), read at the logical shape;
+        # missing ones are zero — the value simply carries no bits beyond
+        # its stored precision
+        limbs = x.logical_limbs()
+        have = limbs.shape[0]
         if have >= n_limbs:
-            return x.limbs[:n_limbs]
-        pad = jnp.zeros((n_limbs - have,) + x.limbs.shape[1:], jnp.bfloat16)
-        return jnp.concatenate([x.limbs, pad], axis=0)
+            return limbs[:n_limbs]
+        pad = jnp.zeros((n_limbs - have,) + limbs.shape[1:], jnp.bfloat16)
+        return jnp.concatenate([limbs, pad], axis=0)
     if x.dtype == jnp.bfloat16:
         # already a single-limb operand; higher limbs are zero
         pad = jnp.zeros((n_limbs - 1,) + x.shape, jnp.bfloat16)

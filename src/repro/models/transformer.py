@@ -12,7 +12,9 @@ Families:
             frame-level cluster logits out              (hubert)
 
 Layers are *scanned* with stacked params (compile-time O(1) in depth) and
-rematerialized (jax.checkpoint) — both mandatory at 60-88 layers.
+rematerialized (jax.checkpoint) — both mandatory at 60-88 layers.  Serving
+decode on prelimbed weights passes per-layer trees instead, and the layers
+unroll (``_scan_layers``).
 """
 from __future__ import annotations
 
@@ -279,19 +281,33 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return jax.checkpoint(fn) if cfg.remat else fn
 
 
-def _scan_layers(layer_fn, h, stacked_params, stacked_cache, cfg):
-    """lax.scan over stacked layer params (+caches), or an unrolled python
-    loop when cfg.scan_layers=False (small smoke models debug).
+def _scan_layers(layer_fn, h, layer_params, stacked_cache, cfg):
+    """Run the layer stack over ``layer_params``: one stacked tree (a
+    leading layer dim on every leaf) or a sequence of per-layer trees.
+
+    A stacked tree is scanned (``lax.scan``: compile O(1) in depth), or
+    walked in an unrolled python loop when cfg.scan_layers=False (small
+    smoke models debug).  A sequence is always walked in Python: each
+    layer's weights are then their own buffers, which a Pallas kernel reads
+    in place, where a scan would copy every layer's slice out of the stack
+    on each call.  The serving engine's prelimbed decode params are such a
+    sequence (serve/engine.prelimb_dense_params); training, prefill and
+    AUTO serving pass stacked params and scan.  The cache stays stacked
+    either way.  Unrolled, the layers' new caches are stacked into a fresh
+    output, as the scan's outputs are: a write-back with ``.at[i].set``
+    would first copy the whole (undonated) input cache into the output.
 
     ``cfg.scan_group > 1`` nests the scan: the outer scan (rematerialized)
     saves one residual carry per *group* of layers instead of per layer —
     activation memory L/g × h instead of L × h at the cost of one in-group
     forward recompute during backward (same recompute as plain per-layer
     remat).  Mandatory for the 88-layer × 12288-wide cells."""
-    n = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
+    per_layer = isinstance(layer_params, (list, tuple))
     aux0 = {"moe_aux": jnp.zeros((), jnp.float32),
             "moe_zloss": jnp.zeros((), jnp.float32)}
-    if cfg.scan_layers:
+    if cfg.scan_layers and not per_layer:
+        n = jax.tree_util.tree_leaves(layer_params)[0].shape[0]
+
         def body(carry, xs):
             h, aux_acc = carry
             lp, lc = xs
@@ -303,7 +319,7 @@ def _scan_layers(layer_fn, h, stacked_params, stacked_cache, cfg):
         if g > 1 and n % g == 0 and n > g:
             regroup = lambda t: jax.tree_util.tree_map(
                 lambda x: x.reshape((n // g, g) + x.shape[1:]), t)
-            gp = regroup(stacked_params)
+            gp = regroup(layer_params)
             gc = (regroup(stacked_cache) if stacked_cache is not None
                   else None)
 
@@ -320,14 +336,16 @@ def _scan_layers(layer_fn, h, stacked_params, stacked_cache, cfg):
             return h, aux, new_caches
 
         (h, aux), new_caches = jax.lax.scan(
-            _maybe_remat(body, cfg), (h, aux0), (stacked_params, stacked_cache))
+            _maybe_remat(body, cfg), (h, aux0), (layer_params, stacked_cache))
         return h, aux, new_caches
     # unrolled
-    aux_tot = {"moe_aux": jnp.zeros((), jnp.float32),
-               "moe_zloss": jnp.zeros((), jnp.float32)}
+    n = (len(layer_params) if per_layer
+         else jax.tree_util.tree_leaves(layer_params)[0].shape[0])
+    aux_tot = aux0
     new_cs = []
     for i in range(n):
-        lp = jax.tree_util.tree_map(lambda x: x[i], stacked_params)
+        lp = (layer_params[i] if per_layer
+              else jax.tree_util.tree_map(lambda x: x[i], layer_params))
         lc = (jax.tree_util.tree_map(lambda x: x[i], stacked_cache)
               if stacked_cache is not None else None)
         h, nc, aux = layer_fn(lp, h, lc)

@@ -21,7 +21,10 @@ weights ONCE per (policy, params) — with ``limbs.prelimb_weight`` at the
 policy's maximum limb count — and runs decode steps against
 :class:`~repro.core.limbs.PrelimbedWeight` operands, which dispatch routes
 through ``mp_matmul_prelimbed_weights`` (the kernel's ``prelimbed_b``
-variant): B-limb extraction leaves the decode loop entirely.  Prefill keeps
+variant): B-limb extraction leaves the decode loop entirely.  The stacks
+are laid out once in the shape the kernel reads — one buffer per layer,
+padded to the kernel's blocks — so a decode step copies no weight bytes
+(:func:`prelimb_dense_params`).  Prefill keeps
 the raw weights (it wants the fused multi-output projection kernel, which
 re-extracts limbs it shares across a whole group).  AUTO policies skip
 pre-limbing — the controller analyzes raw operand values.
@@ -68,36 +71,77 @@ def _policy_prelimb_limbs(policy: PrecisionPolicy) -> Optional[int]:
     return n
 
 
+def _prelimb_padded(w, n_limbs: int) -> PrelimbedWeight:
+    """One weight's limb stack, stored at the kernel's padded shape."""
+    from repro.kernels.ops import prelimbed_storage_shape  # deferred: pallas
+
+    return prelimb_weight(w, n_limbs,
+                          padded=prelimbed_storage_shape(*w.shape[-2:]))
+
+
+def _prelimb_stack(blocks: dict, n_limbs: int):
+    """Prelimb one layer-stacked params group.
+
+    When every leaf outside the prelimb set is a per-layer vector (norm
+    gains: the dense GQA stack), the stack comes back as a list of
+    per-layer trees, each limb stack its own (n_limbs, K', N') buffer,
+    prelimbed layer by layer so the stacked and split stacks are never held
+    at once.  Otherwise (MLA or MoE layers, whose raw matrices a split would
+    copy) it stays stacked, with (L, n_limbs, K', N') limb stacks."""
+    pre = {(g, k) for g, keys in _PRELIMB_LEAVES.items()
+           if isinstance(blocks.get(g), dict) for k in keys if k in blocks[g]}
+    rest = [x for g, sub in blocks.items()
+            for k, x in (sub.items() if isinstance(sub, dict) else [(0, sub)])
+            if (g, k) not in pre]
+    split = bool(pre) and all(x.ndim <= 2
+                              for x in jax.tree_util.tree_leaves(rest))
+
+    def layer(pick):
+        return {g: ({k: (_prelimb_padded(pick(x), n_limbs) if (g, k) in pre
+                         else jax.tree_util.tree_map(pick, x))
+                     for k, x in sub.items()} if isinstance(sub, dict)
+                    else jax.tree_util.tree_map(pick, sub))
+                for g, sub in blocks.items()}
+
+    if not split:
+        return layer(lambda x: x)
+    n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    return [layer(lambda x, i=i: x[i]) for i in range(n)]
+
+
 def prelimb_dense_params(params, n_limbs: int):
     """Decompose the dense-path weight matrices of a transformer params tree
     into :class:`PrelimbedWeight` limb stacks (one-time, per policy), with
     the jnp cascade ``limbs.prelimb_weight`` on every backend.
 
-    Stacked per-layer weights (L, K, N) come back as (L, n_limbs, K, N) —
-    ``lax.scan`` then slices a layer's stack naturally.  Non-dict / absent
-    groups pass through untouched.
+    Each stack is stored zero-padded to the kernel's block multiples
+    (``ops.prelimbed_storage_shape``), so the decode step pads no weight.
+    A dense layer stack comes back as a list of per-layer trees
+    (``_prelimb_stack``): the forward then walks the layers in Python and
+    each kernel reads its layer's stack in place, where a ``lax.scan``
+    would copy every layer's slice out of the stacked weights on every
+    decode step.  Non-dict / absent groups pass through untouched.
     """
-    def leaf(w):
-        return prelimb_weight(w, n_limbs) if w.ndim in (2, 3) else w
-
     out = dict(params)
     for stack_key in ("layers", "dense_layers"):
-        blocks = out.get(stack_key)
-        if not isinstance(blocks, dict):
-            continue
-        blocks = dict(blocks)
-        for group, keys in _PRELIMB_LEAVES.items():
-            if isinstance(blocks.get(group), dict):
-                sub = dict(blocks[group])
-                for k in keys:
-                    if k in sub:
-                        sub[k] = leaf(sub[k])
-                blocks[group] = sub
-        out[stack_key] = blocks
+        if isinstance(out.get(stack_key), dict):
+            out[stack_key] = _prelimb_stack(out[stack_key], n_limbs)
     for head in ("lm_head", "ctc_head"):
         if isinstance(out.get(head), dict) and "w" in out[head]:
-            out[head] = {**out[head], "w": leaf(out[head]["w"])}
+            out[head] = {**out[head],
+                         "w": _prelimb_padded(out[head]["w"], n_limbs)}
     return out
+
+
+def prelimb_pad_bytes(tree) -> int:
+    """Bytes of zeros the padded limb stacks of ``tree`` store."""
+    total = 0
+    for w in jax.tree_util.tree_leaves(
+            tree, is_leaf=lambda x: isinstance(x, PrelimbedWeight)):
+        if isinstance(w, PrelimbedWeight):
+            logical = int(np.prod(w.limbs.shape[:-2])) * w.kn[0] * w.kn[1]
+            total += (w.limbs.size - logical) * w.limbs.dtype.itemsize
+    return total
 
 
 @dataclasses.dataclass
@@ -393,13 +437,16 @@ class ServeEngine:
         """Trace/cache observability counters (merged into scheduler
         ``stats()``): ``trace_events`` counts jit traces actually executed;
         the hit/miss pairs cover the jit'd-step LRU and the prelimbed-weight
-        cache."""
+        cache; ``prelimb_pad_bytes`` is the memory the cached limb stacks
+        spend on zero padding to the kernel's blocks."""
         return {
             "trace_events": self.trace_events,
             "step_cache_hits": self.step_cache_hits,
             "step_cache_misses": self.step_cache_misses,
             "prelimb_cache_hits": self.prelimb_cache_hits,
             "prelimb_cache_misses": self.prelimb_cache_misses,
+            "prelimb_pad_bytes": sum(prelimb_pad_bytes(t) for t
+                                     in self._prelimb_cache.values()),
         }
 
     # -- single-request path (prefill writes the whole pool cache; simple and

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e at
 the paper model's widths (``paper-mpfp-100m``: d_model 768, 12 heads of 64,
-d_ff 3072, vocab 32000), on a described ``v5e:2x2`` topology — no chip.
+d_ff 3072, vocab 32000), on a described ``v5e:2x2`` topology — no chip; and
+of the paged decode step at ChatGLM3-6B's widths, the benchmark's model.
 
 Interpret mode accepts kernels that Mosaic refuses (grid / dimension-
 semantics mismatches, unaligned tiles, VMEM overruns); these compiles catch
@@ -12,16 +13,23 @@ The topology is described inside a module fixture, never at import: only one
 process may load the TPU library at a time, so every test worker must
 collect the same tests and only the one given this file loads it.
 """
+import json
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import ModelConfig
 from repro.configs.registry import get_config
+from repro.core import dispatch
 from repro.core.limbs import PrelimbedWeight
+from repro.core.policy import PrecisionPolicy
 from repro.kernels import mp_attention, ops
+from repro.models import transformer as T
+from repro.serve.engine import make_paged_decode_step, prelimb_dense_params
 
 CFG = get_config("paper-mpfp-100m")
 D, FF, V = CFG.d_model, CFG.d_ff, CFG.vocab
@@ -139,3 +147,49 @@ def test_mixed_paged_attention(one_chip):
                 q, kp, vp, t, ln, "M23", "M23", a, b, c, d),
         one_chip, ((DECODE_SLOTS, H, DH), F32), POOL, POOL, TABLE, SLOTS,
         SLOTS, SLOTS, SLOTS, SLOTS, kernel="mp_mixed_paged_attention")
+
+
+# ChatGLM3-6B at its published widths (bench/configs/chatglm3-6b-3l.json),
+# two layers: d_ff 13696 is not a multiple of the kernel's 256-wide blocks
+GLM = ModelConfig(name="chatglm3-6b-2l", family="dense", n_layers=2,
+                  d_model=4096, vocab=65024, n_heads=32, n_kv_heads=2,
+                  head_dim=128, d_ff=13696, rope_theta=10000.0,
+                  rope_fraction=0.5, norm_eps=1e-5, max_seq=8192)
+MM_FAMILY = Path(__file__).resolve().parents[1] / "bench" / "kernels" \
+    / "mp_matmul.json"
+
+
+def test_paged_decode_reads_limb_stacks_in_place(one_chip, monkeypatch):
+    """The paged decode step on prelimbed M23 weights hands every matmul
+    kernel its layer's stored limb stack: no ``pad`` and no
+    ``dynamic-slice`` makes a bf16 limb stack, and each prelimbed kernel
+    still shows the operands the benchmark's mp-matmul family matches
+    (activations f32[M,K], weights bf16[n,K,N]), which
+    ``mm_roofline.decode`` reads."""
+    monkeypatch.setattr(dispatch, "kernel_interpret", lambda backend: False)
+    policy = PrecisionPolicy.serve_default().overlay("M23")
+    put = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    raw = jax.eval_shape(lambda k: T.init_params(GLM, k),
+                         jax.random.PRNGKey(0))
+    params = put(jax.eval_shape(lambda p: prelimb_dense_params(p, 3), raw))
+    slots, blocks = 32, 129
+    pool = ((GLM.n_layers, blocks, BLOCK, GLM.n_kv_heads, 128), F32)
+    shapes = [pool, pool, ((slots, TABLE_W), I32), ((slots,), I32),
+              ((slots, 1), I32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    step = dispatch.pin_backend(make_paged_decode_step(GLM, policy), "pallas")
+    text = jax.jit(step).lower(params, *args).compile().as_text()
+
+    copies = re.findall(r"= bf16\[\d+,\d+,\d+\]\S* (?:pad|dynamic-slice)\(|"
+                        r"%(?:pad|dynamic-slice)\S*fusion\S* = bf16\[\d+,\d+,\d+\]",
+                        text)
+    assert not copies, copies
+    calls = [line for line in text.splitlines()
+             if re.search(r"%mp_matmul_prelimbed(\.\d+)? = .* custom-call\(",
+                          line)]
+    assert len(calls) == 7 * GLM.n_layers + 1  # qkv, o, gate, up, down; head
+    patterns = json.loads(MM_FAMILY.read_text())["patterns"]
+    for line in calls:
+        assert any(re.search(p, line) for p in patterns), line[:200]
