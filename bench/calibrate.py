@@ -6,10 +6,10 @@ control's, one JSON line per seed.
     python bench/calibrate.py --workload glm3l-m23-closed \
         --seeds 11,12,13 --seconds 10
 
-The control is the plain reference put in the program's place, one
-precision below each request's format (``bench/reference/dense.py``:
-``bf16``, ``high``); it reads the gap of the token that the lower
-precision puts first at each served position.  A limit lies between the
+The control is the configuration's plain reference put in the program's
+place, one precision below each request's format (``bf16``, ``high``); it
+reads the gap of the token that the lower precision puts first at each
+served position.  A limit lies between the
 largest program reading and the smallest control reading (PERF.md gives
 both).  This is a tool for setting limits, not part of a benchmark run.
 """

@@ -1,5 +1,6 @@
 """What decides ``correct``: the tokens the timed path served, against the
-plain float32 reference.
+plain float32 reference that the cell's configuration file names
+(``"reference"``, a module under ``bench/reference/``).
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed, is run through the reference
@@ -14,6 +15,15 @@ precision serves the wrong twin and reads a wider gap.  Every
 request is greedy, so that is the whole check of what was served.  A
 second number counts finished requests whose token count is not the
 ``max_new`` they asked for (limit 0).
+
+A reference may return, beside each position's gap, whether it decided
+that position (``compared``): where a discrete choice inside the model,
+such as an expert's selection, lies within the reference's own rounding
+of a tie, it may declare the position undecidable by a rule of its own,
+documented there.  The widest gap is then taken over the compared served
+positions only, and ``excluded_<format>`` is the share (0 to 1) of the
+format's served positions in the sample that were not compared.  Like
+every number, it fails without a limit of the cell's.
 """
 from __future__ import annotations
 
@@ -22,7 +32,6 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from bench.reference import dense
 from bench.traffic import rng_for
 
 BUCKET = 256   # reference sequence lengths are padded to a multiple of this
@@ -63,22 +72,35 @@ def _padded(prompt: np.ndarray, served: Sequence[int]) -> Tuple:
     return tokens, cand, slice(n - 1, n - 1 + len(served))
 
 
-def widest_gaps(params: dict, model: dict, picked: Sequence,
+def widest_gaps(reference, params: dict, model: dict, picked: Sequence,
                 precisions: Sequence[str] = ("highest",)
                 ) -> Dict[str, float]:
     """``gap_<format>`` at ``highest`` (the program's served tokens), and
     ``gap_<format>@<precision>`` for each lower precision (that
-    precision's own argmax: the control)."""
+    precision's own argmax: the control), by the module ``reference``'s
+    ``gaps``.  Where it returns ``compared``, also ``excluded_<format>``
+    (and ``@<precision>``): the share of served positions not compared."""
     out: Dict[str, float] = {}
+    served: Dict[str, int] = {}
+    excluded: Dict[str, int] = {}
     head_t = np.ascontiguousarray(np.asarray(params["lm_head"]["w"]).T)
     for t in picked:
         tokens, cand, sl = _padded(t.req.prompt, t.sreq.out)
         for prec in precisions:
-            g, _ = dense.gaps(params, head_t, tokens, cand, model, prec)
-            w = float(np.max(g[sl]))
-            key = f"gap_{t.req.mode}" + ("" if prec == "highest"
-                                         else f"@{prec}")
-            out[key] = max(out.get(key, 0.0), w)
+            g, _, *compared = reference.gaps(params, head_t, tokens, cand,
+                                             model, prec)
+            suffix = t.req.mode + ("" if prec == "highest" else f"@{prec}")
+            g = g[sl]
+            if compared:
+                mask = np.asarray(compared[0], bool)[sl]
+                served[suffix] = served.get(suffix, 0) + len(g)
+                excluded[suffix] = (excluded.get(suffix, 0)
+                                    + int(np.sum(~mask)))
+                g = g[mask]
+            w = float(np.max(g)) if len(g) else 0.0
+            out[f"gap_{suffix}"] = max(out.get(f"gap_{suffix}", 0.0), w)
+    for suffix, n in served.items():
+        out[f"excluded_{suffix}"] = excluded[suffix] / n
     return out
 
 

@@ -1,7 +1,7 @@
 """step_mfu: model FLOPs of the tokens that the phase's ticks of the
-measured window served (their prefills and decoded tokens) over the host
+measured window served (their prefills and decoded tokens), as the cell's
+reference counts them (``prefill_flops``, ``decode_flops``), over the host
 time of those ticks' ``step()`` spans times the chip's bf16 peak, in %."""
-from bench import roofline
 
 
 def read(ctx, phase):
@@ -11,9 +11,9 @@ def read(ctx, phase):
         return None
     flops = 0.0
     for s in steps:
-        flops += sum(roofline.prefill_flops(ctx.model, n)
+        flops += sum(ctx.reference.prefill_flops(ctx.model, n)
                      for n, _ in s.prefills)
-        flops += sum(roofline.decode_flops(ctx.model, c)
+        flops += sum(ctx.reference.decode_flops(ctx.model, c)
                      for c, _ in s.decodes)
     if flops <= 0:
         return None

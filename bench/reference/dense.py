@@ -18,7 +18,11 @@ permutation of the wq/wk columns), and ChatGLM3's QKV bias is left out.
 
 The gap that decides ``correct`` (``gaps``) takes the lm_head of the
 compared tokens in float64 from the float32 final hidden state, so the
-reference's own float32 rounding of the logits does not enter it.
+reference's own float32 rounding of the logits does not enter it.  The
+dense block has no discrete choice inside it (no routing), so this
+reference decides every position: ``gaps`` returns no ``compared`` mask.
+``prefill_flops`` and ``decode_flops`` are the family's model FLOPs, which
+``step_mfu`` reads.
 
 Every matmul runs at one ``precision``: ``highest`` (float32, the
 reference), or one of the lower controls: ``high`` (three bf16 passes, hi·hi
@@ -30,9 +34,13 @@ from __future__ import annotations
 
 import functools
 
+from typing import Tuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.roofline import layer_matmuls, lm_head_matmul
 
 PRECISIONS = ("highest", "high", "bf16", "fp8")
 _F8_MAX = 448.0
@@ -171,7 +179,8 @@ def gaps(params: dict, head_t, tokens, cand, model: dict,
     float32 top candidates, their lm_head twins (``id ^ 1``) and the chosen
     token.  At ``highest`` the chosen token is ``cand[t]`` (what the
     program served); at a lower precision it is that precision's own argmax
-    (the control).  Returns (gaps, chosen) as numpy arrays."""
+    (the control).  Returns (gaps, chosen) as numpy arrays: every position
+    is decided, so there is no third array (``compared``)."""
     items = tuple(sorted((k, v) for k, v in model.items()
                          if not isinstance(v, (dict, list))))
     h, top, pick = _read(params, tokens, items, precision)
@@ -182,3 +191,32 @@ def gaps(params: dict, head_t, tokens, cand, model: dict,
     ids = np.minimum(ids, model["vocab"] - 1)
     ex = exact_logits(h, head_t, ids)
     return ex.max(axis=1) - ex[:, 2 * TOP_K], chosen
+
+
+def _matmul_params(model: dict) -> Tuple[int, int]:
+    layers = model["n_layers"] * sum(k * n for k, n in layer_matmuls(model))
+    k, n = lm_head_matmul(model)
+    return layers, k * n
+
+
+def _attn_width(model: dict) -> int:
+    dh = model.get("head_dim") or model["d_model"] // model["n_heads"]
+    return model["n_heads"] * dh
+
+
+def prefill_flops(model: dict, n: int) -> float:
+    """Model FLOPs of prefilling ``n`` prompt tokens: 2 per matmul weight
+    per token, causal attention at 4·L·context·d_attn per token (context t
+    for position t), and the lm_head once (only the last position is
+    needed)."""
+    layers, head = _matmul_params(model)
+    attn = 4.0 * model["n_layers"] * _attn_width(model) * n * (n + 1) / 2
+    return 2.0 * layers * n + attn + 2.0 * head
+
+
+def decode_flops(model: dict, context: int) -> float:
+    """Model FLOPs of one decoded token attending over ``context``
+    positions (itself included)."""
+    layers, head = _matmul_params(model)
+    return (2.0 * (layers + head)
+            + 4.0 * model["n_layers"] * _attn_width(model) * context)

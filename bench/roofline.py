@@ -1,5 +1,6 @@
-"""The yardstick's arithmetic: chip peaks, the least time of a matmul, and
-the model FLOPs of served tokens.
+"""The yardstick's arithmetic: chip peaks and the least time of a matmul
+(the model FLOPs of served tokens are the configuration's reference's:
+``prefill_flops`` and ``decode_flops`` in ``bench/reference/<family>.py``).
 
 Everything here is computed from shapes and the configuration file, never
 from what the program reports, so a later change to the program cannot move
@@ -72,35 +73,6 @@ def launch_least_s(model: dict, layer_rows: int, head_rows: int, b: int,
     k, n = lm_head_matmul(model)
     return (model["n_layers"] * per_layer
             + matmul_least_s(head_rows, k, n, b, peak))
-
-
-def _matmul_params(model: dict) -> Tuple[int, int]:
-    layers = model["n_layers"] * sum(k * n for k, n in layer_matmuls(model))
-    k, n = lm_head_matmul(model)
-    return layers, k * n
-
-
-def _attn_width(model: dict) -> int:
-    dh = model.get("head_dim") or model["d_model"] // model["n_heads"]
-    return model["n_heads"] * dh
-
-
-def prefill_flops(model: dict, n: int) -> float:
-    """Model FLOPs of prefilling ``n`` prompt tokens: 2 per matmul weight
-    per token, causal attention at 4·L·context·d_attn per token (context t
-    for position t), and the lm_head once (only the last position is
-    needed)."""
-    layers, head = _matmul_params(model)
-    attn = 4.0 * model["n_layers"] * _attn_width(model) * n * (n + 1) / 2
-    return 2.0 * layers * n + attn + 2.0 * head
-
-
-def decode_flops(model: dict, context: int) -> float:
-    """Model FLOPs of one decoded token attending over ``context``
-    positions (itself included)."""
-    layers, head = _matmul_params(model)
-    return (2.0 * (layers + head)
-            + 4.0 * model["n_layers"] * _attn_width(model) * context)
 
 
 def dense_matmuls(model: dict, launches: Iterable[dict],

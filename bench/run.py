@@ -4,15 +4,17 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json``) names a configuration file under
-``bench/configs/`` and a traffic file under ``bench/traffic/``; the run
-makes the weights from the seed on the device, builds the launcher's
-engine and continuous scheduler (``repro.launch.serve.make_scheduler``)
-with the Pallas kernels, warms up every shape the traffic reaches
-(``bench/warmup.py``), pre-rolls the traffic into its steady state, and
-then measures for ``--seconds`` while ``bench/driver.py`` sends the
-requests through ``submit()``/``step()``.  Afterwards the served tokens of
-a sample of finished requests are compared with the plain float32
-reference (``bench/check.py``).
+``bench/configs/`` and a traffic file under ``bench/traffic/``; the
+configuration file names the module that builds its weights
+(``weights.module``) and its plain reference (``reference``), so a new
+model family enters by new files.  The run makes the weights from the
+seed on the device, builds the launcher's engine and continuous scheduler
+(``repro.launch.serve.make_scheduler``) with the Pallas kernels, warms up
+every shape the traffic reaches (``bench/warmup.py``), pre-rolls the
+traffic into its steady state, and then measures for ``--seconds`` while
+``bench/driver.py`` sends the requests through ``submit()``/``step()``.
+Afterwards the served tokens of a sample of finished requests are compared
+with the configuration's plain float32 reference (``bench/check.py``).
 
 Earlier lines on stderr say how set-up went, how late the generator ran
 and how many programs compiled inside the window; the last lines on stderr
@@ -39,6 +41,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Dict, List, Optional, Sequence  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
@@ -62,6 +65,8 @@ class Cell:
     limits: dict          # bench/limits/<cell>.json (number -> limit)
     end_to_end: List[dict]
     per_layer: List[dict]
+    reference: ModuleType  # the module the configuration's "reference" names
+    root: Path = ROOT     # the checkout the cell's files are read from
 
 
 def log(msg: str) -> None:
@@ -73,22 +78,36 @@ def _for_cell(metrics: Sequence[dict], cell: str) -> List[dict]:
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
+    """The cell ``name`` of ``<root>/BENCHMARK.json`` with its files.  Its
+    configuration file has to name its weights builder
+    (``weights.module``) and its reference (``reference``): both are
+    loaded here, and there is no default family."""
+    from bench import plug
+
     bench = json.loads((root / "BENCHMARK.json").read_text())
     work = {w["name"]: w for w in bench["workloads"]}
     if name not in work:
         raise SystemExit(f"unknown workload {name!r}; have {sorted(work)}")
     w = work[name]
-    conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = json.loads((root / entry["file"]).read_text())
+    for key, value in (("weights.module",
+                        config.get("weights", {}).get("module")),
+                       ("reference", config.get("reference"))):
+        if value is None:
+            raise SystemExit(f"{entry['file']}: no {key!r}; a configuration "
+                             f"names its weights module and its reference")
+    plug.load(config["weights"]["module"], root)
     limits_file = root / "bench" / "limits" / f"{name}.json"
     return Cell(
-        name=name, chips=w["chips"],
-        config=json.loads((root / conf["file"]).read_text()),
+        name=name, chips=w["chips"], config=config,
         traffic=json.loads(
             (root / "bench" / "traffic" / f"{w['traffic']}.json").read_text()),
         limits=(json.loads(limits_file.read_text())
                 if limits_file.exists() else {}),
         end_to_end=_for_cell(bench["end_to_end"], name),
-        per_layer=_for_cell(bench["per_layer"], name))
+        per_layer=_for_cell(bench["per_layer"], name),
+        reference=plug.load(config["reference"], root), root=root)
 
 
 def configure_jax_cache(root: Path = ROOT) -> str:
@@ -148,9 +167,19 @@ class CompileCounter:
 
 
 def model_config(conf: dict):
+    """The program's ``ModelConfig`` of a configuration file: its
+    ``model`` object, with the nested ``mla``, ``moe`` and ``ssm`` objects
+    as the program's dimension classes."""
     from repro.configs.base import ModelConfig
+    from repro.models.mla import MLADims
+    from repro.models.moe import MoEDims
+    from repro.models.ssm import SSMDims
 
-    return ModelConfig(name=conf["name"], **conf["model"])
+    kw = dict(conf["model"])
+    for key, dims in (("mla", MLADims), ("moe", MoEDims), ("ssm", SSMDims)):
+        if isinstance(kw.get(key), dict):
+            kw[key] = dims(**kw[key])
+    return ModelConfig(name=conf["name"], **kw)
 
 
 def engine_policy(traffic: dict):
@@ -216,6 +245,7 @@ class ReadContext:
     model: dict
     peak: Dict[str, float]
     window_steps: list               # StepRec of the measured window
+    reference: ModuleType            # the cell's reference (model FLOPs)
     ttft: List[float] = dataclasses.field(default_factory=list)  # ttft_ms
     trace: object = None             # bench.trace.Trace of a traced run
     periods: Optional[list] = None   # (StepRec, lo_ns, hi_ns), traced ticks
@@ -317,8 +347,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     compiles = CompileCounter()
     marks = {"imports": time.perf_counter()}
 
-    params = weights.make_params(
-        model, seed, cell.config["weights"]["lm_head_twin_scale"])
+    params = weights.make_params(cell.config, seed, cell.root)
     marks["weights"] = time.perf_counter()
     cfg = model_config(cell.config)
     sched = make_scheduler(cfg, params, slots=spec["slots"],
@@ -397,6 +426,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             periods = None
         ctx = ReadContext(model=model, peak=peak,
                           window_steps=drv.window_steps(),
+                          reference=cell.reference,
                           ttft=ttft_ms(drv), trace=tr,
                           periods=periods, kernels=kernel_families())
         result["metrics"] = per_layer(cell, ctx)
@@ -429,7 +459,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     gc.collect()
     t_chk = time.perf_counter()
     picked = check.sample(finished, seed, spec["check"])
-    numbers = check.widest_gaps(params, model, picked,
+    numbers = check.widest_gaps(cell.reference, params, model, picked,
                                 ("highest",) + tuple(controls))
     numbers["wrong_length"] = float(check.wrong_length(finished))
     program = {k: v for k, v in numbers.items() if "@" not in k}

@@ -45,8 +45,7 @@ def main() -> int:
     spec = cell.traffic
     model = cell.config["model"]
     rates = [float(r) for r in args.rates.split(",")]
-    params = weights.make_params(
-        model, args.seed, cell.config["weights"]["lm_head_twin_scale"])
+    params = weights.make_params(cell.config, args.seed, cell.root)
     sched = make_scheduler(R.model_config(cell.config), params,
                            slots=spec["slots"], max_seq=spec["max_seq"],
                            policy=R.engine_policy(spec),

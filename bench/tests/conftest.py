@@ -4,6 +4,7 @@
 
 They use the smoke sizes of both configurations and the Pallas kernels in
 the interpreter (``backend="pallas_interpret"``)."""
+import json
 import os
 import sys
 from pathlib import Path
@@ -23,6 +24,14 @@ SMOKE = {
         "max_seq": 128, "attn_q_chunk": 16, "attn_kv_chunk": 16},
 }
 TWIN_SCALE = 3e-5   # the configuration's lm_head_twin_scale
+
+
+def smoke_conf(config: str) -> dict:
+    """A configuration file as the harness reads it, its model cut to the
+    smoke size: it names the same weights module and reference."""
+    conf = json.loads((ROOT / "bench" / "configs" / f"{config}.json")
+                      .read_text())
+    return dict(conf, model=SMOKE[config])
 
 
 def smoke_traffic(base: dict, **over) -> dict:

@@ -9,7 +9,7 @@ import time
 import numpy as np
 import pytest
 
-from conftest import SMOKE, TWIN_SCALE, smoke_traffic
+from conftest import smoke_conf, smoke_traffic
 
 import bench.run as R
 from bench import check
@@ -23,9 +23,7 @@ SMOKE_LIMITS = {"gap_M23": 2e-6, "wrong_length": 0.0}
 
 def smoke_cell(name: str = CELL, **traffic) -> R.Cell:
     cell = R.load_cell(name)
-    conf = {"name": cell.config["name"], "model": SMOKE[cell.config["name"]],
-            "weights": {"lm_head_twin_scale": TWIN_SCALE}}
-    return dataclasses.replace(cell, config=conf,
+    return dataclasses.replace(cell, config=smoke_conf(cell.config["name"]),
                                traffic=smoke_traffic(cell.traffic, **traffic),
                                limits=SMOKE_LIMITS)
 

