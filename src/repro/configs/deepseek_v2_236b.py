@@ -1,6 +1,7 @@
 """DeepSeek-V2 236B — MoE with Multi-head Latent Attention.
 [arXiv:2405.04434; hf]  60L d_model=5120 128H d_ff(expert)=1536 vocab=102400,
-160 routed experts top-6 + 2 shared, MLA kv_lora=512."""
+160 routed experts top-6 (softmax, not renormalised, weighed 16 times) + 2
+shared, MLA kv_lora=512."""
 from repro.configs.base import ModelConfig
 from repro.models.mla import MLADims
 from repro.models.moe import MoEDims
@@ -18,7 +19,8 @@ CONFIG = ModelConfig(
                 qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128),
     moe=MoEDims(d_model=5120, n_experts=160, top_k=6, expert_ff=1536,
                 n_shared=2, capacity_factor=1.25, n_chunks=4,
-                dispatch_dtype="float32"),
+                dispatch_dtype="float32", norm_topk_prob=False,
+                routed_scaling_factor=16.0),
     first_k_dense=1,
     dense_ff=12288,
     max_seq=32768,

@@ -1,6 +1,7 @@
 """DeepSeek-V2-Lite 16B — MoE + MLA (no query compression in Lite).
 [arXiv:2405.04434; hf]  27L d_model=2048 16H d_ff(expert)=1408 vocab=102400,
-64 routed experts top-6 + 2 shared, MLA kv_lora=512."""
+64 routed experts top-6 (softmax, not renormalised) + 2 shared, MLA kv_lora=512
+with YaRN rope (factor 40, mscale 0.707)."""
 from repro.configs.base import ModelConfig
 from repro.models.mla import MLADims
 from repro.models.moe import MoEDims
@@ -15,9 +16,13 @@ CONFIG = ModelConfig(
     n_kv_heads=16,
     d_ff=1408,
     mla=MLADims(d_model=2048, n_heads=16, kv_lora=512, q_lora=0,
-                qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128),
+                qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+                yarn_factor=40.0, yarn_original_max_pos=4096,
+                yarn_beta_fast=32.0, yarn_beta_slow=1.0, yarn_mscale=0.707,
+                yarn_mscale_all_dim=0.707),
     moe=MoEDims(d_model=2048, n_experts=64, top_k=6, expert_ff=1408,
-                n_shared=2, capacity_factor=1.25, n_chunks=2),
+                n_shared=2, capacity_factor=1.25, n_chunks=2,
+                norm_topk_prob=False),
     first_k_dense=1,
     dense_ff=10944,
     max_seq=32768,

@@ -732,3 +732,75 @@ def dispatch_paged_attention(
         vv = jnp.repeat(vv, n_rep, axis=2)
     return masked_decode_attention(q, kk, vv, lengths, mode_qk, mode_pv,
                                    scale=scale, backend=name)
+
+
+def masked_latent_attention(
+    q_lat: jax.Array,
+    q_rope: jax.Array,
+    c_kv: jax.Array,
+    k_rope: jax.Array,
+    lengths,
+    mode_qk: FormatLike,
+    mode_pv: Optional[FormatLike] = None,
+    *,
+    scale: float,
+    backend: Optional[str] = None,
+) -> jax.Array:
+    """Latent attention (MLA's absorbed decode) as policy-obeying einsums:
+    q_lat (B, H, Dc) and q_rope (B, H, Dr) against the latent cache c_kv
+    (B, T, Dc) and rope keys k_rope (B, T, Dr), masked by ``lengths``
+    (scalar or (B,)).  Logits ``scale · (q_lat · c + q_rope · r)`` at the
+    ``attn_qk`` format (queries scaled before the contraction, as the
+    kernels do), context ``p · c`` at ``attn_pv``.  Returns (B, H, Dc)."""
+    from repro.core.mpmatmul import mp_matmul  # lazy: mpmatmul imports us
+
+    mode_pv = mode_pv if mode_pv is not None else mode_qk
+    T = c_kv.shape[1]
+    c = c_kv.astype(jnp.float32)
+    logits = (mp_matmul(q_lat.astype(jnp.float32) * scale,
+                        jnp.swapaxes(c, -1, -2), mode_qk, backend=backend)
+              + mp_matmul(q_rope.astype(jnp.float32) * scale,
+                          jnp.swapaxes(k_rope.astype(jnp.float32), -1, -2),
+                          mode_qk, backend=backend))          # (B, H, T)
+    ln = lengths.reshape(-1, 1, 1) if getattr(lengths, "ndim", 0) \
+        else lengths
+    mask = jnp.arange(T)[None, None, :] < ln
+    logits = jnp.where(mask, logits, ref_backend.ATTN_NEG_INF)
+    p = jnp.where(mask, jax.nn.softmax(logits, axis=-1), 0.0)
+    return mp_matmul(p, c, mode_pv, backend=backend)
+
+
+def dispatch_latent_paged_attention(
+    q_lat: jax.Array,
+    q_rope: jax.Array,
+    c_pool: jax.Array,
+    r_pool: jax.Array,
+    block_table: jax.Array,
+    lengths: jax.Array,
+    mode_qk: FormatLike,
+    mode_pv: Optional[FormatLike] = None,
+    *,
+    scale: float,
+    backend: Optional[str] = None,
+) -> jax.Array:
+    """Route one paged latent-attention step: q_lat (B, H, Dc), q_rope (B,
+    H, Dr) against the latent pool c_pool (n_blocks, bs, Dc) / r_pool
+    (n_blocks, bs, Dr) through the slot block tables -> context (B, H, Dc).
+
+    pallas / pallas_interpret run the latent paged kernel (each pool block
+    DMA'd once for every head); other backends, and AUTO formats, gather
+    the table's columns and run :func:`masked_latent_attention`."""
+    name = backend or context_lib.current_context().backend
+    if name in ("pallas", "pallas_interpret") and not (
+            is_auto(mode_qk) or is_auto(mode_pv if mode_pv is not None
+                                        else mode_qk)):
+        from repro.kernels import mp_attention as attn_kernels
+
+        return attn_kernels.mp_latent_paged_attention_pallas(
+            q_lat, q_rope, c_pool, r_pool, block_table, lengths, mode_qk,
+            mode_pv, scale=scale, interpret=kernel_interpret(name))
+    B = block_table.shape[0]
+    c = c_pool[block_table].reshape((B, -1) + c_pool.shape[2:])
+    r = r_pool[block_table].reshape((B, -1) + r_pool.shape[2:])
+    return masked_latent_attention(q_lat, q_rope, c, r, lengths, mode_qk,
+                                   mode_pv, scale=scale, backend=name)

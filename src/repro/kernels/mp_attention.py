@@ -15,7 +15,7 @@ MXU passes stay mode-proportional: n_products(attn_qk) + n_products(attn_pv)
 per tile pair — the paper's reconfigurable multiplier driving both attention
 contractions at independently policy-resolved formats.
 
-Two variants:
+Three variants:
 
   * ``mp_attention_pallas`` — training/prefill: grid (B·H, nq, nkv), kv
     innermost sequential; per-q-block (m, d, acc) scratch persists across kv
@@ -27,6 +27,10 @@ Two variants:
     contiguous (B, W·bs) copy of the cache in HBM — and per-slot lengths
     mask the tail.  Inactive slots (all-trash rows, length 0) produce exact
     zeros.
+  * ``mp_latent_paged_attention_pallas`` — the same for latent attention
+    (MLA's absorbed decode): every query head of a slot against one latent
+    pool entry per position, MQA over ``c_kv`` with a rope key beside it;
+    each pool block is read once for all heads.
 
 Numerical structure is shared with the ref backend: both call the
 ``attn_qk_logits`` / ``online_softmax_update`` helpers in kernels/ref.py, so
@@ -348,6 +352,116 @@ def mp_paged_attention_pallas(
     )
     return call(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
                 q.astype(jnp.float32), k_pool, v_pool)
+
+
+# ---------------------------------------------------------------------------
+# latent paged decode kernel (MLA's absorbed decode)
+# ---------------------------------------------------------------------------
+def _latent_paged_kernel(tbl_ref, len_ref, ql_ref, qr_ref, c_ref, r_ref,
+                         o_ref, m_scr, d_scr, acc_scr, *, fmt_qk, fmt_pv,
+                         scale: float, out_dtype):
+    """Grid (B, W): one (slot, table-column) per step, columns sequential.
+    q_lat (1, H, Dc), q_rope (1, H, Dr); c (1, bs, Dc), r (1, bs, Dr) — the
+    pool block the slot's table names for this column.  Logits at the QK
+    format: ``q_lat · c + q_rope · r``; the context ``p · c`` at the PV
+    format."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    bs = c_ref.shape[1]
+    H = ql_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        d_scr[...] = jnp.zeros_like(d_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length = len_ref[b]
+
+    @pl.when(j * bs < length)  # skip columns entirely past the slot's length
+    def _body():
+        ql = ql_ref[0].astype(jnp.float32) * scale     # (H, Dc)
+        qr = qr_ref[0].astype(jnp.float32) * scale     # (H, Dr)
+        cb = c_ref[0].astype(jnp.float32)              # (bs, Dc)
+        rb = r_ref[0].astype(jnp.float32)              # (bs, Dr)
+        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (H, bs), 1)
+        valid = pos < length
+        logits = (ref_backend.attn_qk_logits(ql, cb, fmt_qk,
+                                             split=_extract_limbs)
+                  + ref_backend.attn_qk_logits(qr, rb, fmt_qk,
+                                               split=_extract_limbs))
+        logits = jnp.where(valid, logits, NEG_INF)
+        m, d, acc = ref_backend.online_softmax_update(
+            m_scr[:, 0], d_scr[:, 0], acc_scr[...], logits, cb, fmt_pv,
+            p_mask=valid, split=_extract_limbs)
+        m_scr[...] = jnp.broadcast_to(m[:, None], m_scr.shape)
+        d_scr[...] = jnp.broadcast_to(d[:, None], d_scr.shape)
+        acc_scr[...] = acc
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _flush():
+        d = jnp.maximum(d_scr[:, 0], 1e-30)
+        o_ref[0] = (acc_scr[...] / d[:, None]).astype(out_dtype)
+
+
+def mp_latent_paged_attention_pallas(
+    q_lat: jax.Array,
+    q_rope: jax.Array,
+    c_pool: jax.Array,
+    r_pool: jax.Array,
+    block_table: jax.Array,
+    lengths: jax.Array,
+    mode_qk: FormatLike = "M16",
+    mode_pv: Optional[FormatLike] = None,
+    *,
+    scale: float,
+    out_dtype=jnp.float32,
+    interpret: bool = False,
+) -> jax.Array:
+    """Paged latent attention: H query heads per slot as MQA over the
+    latent pool, blocks DMA'd through the scalar-prefetched block table.
+
+    q_lat: (B, H, Dc); q_rope: (B, H, Dr); c_pool: (n_blocks, bs, Dc);
+    r_pool: (n_blocks, bs, Dr); block_table: (B, W) int32; lengths: (B,)
+    int32.  Returns the context (B, H, Dc): the softmax of ``scale ·
+    (q_lat · c + q_rope · r)`` over the slot's positions, applied to c."""
+    B, H, Dc = q_lat.shape
+    Dr = q_rope.shape[-1]
+    n_blocks, bs, dc = c_pool.shape
+    assert dc == Dc and r_pool.shape == (n_blocks, bs, Dr), (
+        q_lat.shape, q_rope.shape, c_pool.shape, r_pool.shape)
+    W = block_table.shape[1]
+    fmt_qk = resolve(mode_qk)
+    fmt_pv = resolve(mode_pv if mode_pv is not None else mode_qk)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, W),
+        in_specs=[
+            pl.BlockSpec((1, H, Dc), lambda b, j, tbl, ln: (b, 0, 0)),
+            pl.BlockSpec((1, H, Dr), lambda b, j, tbl, ln: (b, 0, 0)),
+            pl.BlockSpec((1, bs, Dc), lambda b, j, tbl, ln: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, bs, Dr), lambda b, j, tbl, ln: (tbl[b, j], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, H, Dc), lambda b, j, tbl, ln: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, Dc), jnp.float32),
+        ],
+    )
+    call = pl.pallas_call(
+        functools.partial(_latent_paged_kernel, fmt_qk=fmt_qk, fmt_pv=fmt_pv,
+                          scale=scale, out_dtype=out_dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Dc), out_dtype),
+        compiler_params=_compiler_params(PAGED_SEMANTICS),
+        interpret=interpret,
+        name="mp_latent_paged_attention",
+    )
+    return call(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+                q_lat.astype(jnp.float32), q_rope.astype(jnp.float32),
+                c_pool, r_pool)
 
 
 # ---------------------------------------------------------------------------

@@ -374,7 +374,8 @@ def _decode_attention(q, k_cache, v_cache, length, dims: AttnDims,
 
 def _paged_write(cache: PagedKVCache, k: jax.Array, v: jax.Array,
                  positions: jax.Array) -> PagedKVCache:
-    """Scatter S new K/V tokens per slot into the paged block pool.
+    """Scatter S new K/V tokens per slot into the paged block pool (k/v
+    (B, S) + the pool's entry shape: (Hkv, Dh), or a latent width).
 
     ``positions`` (B, S) are the absolute token positions being written; each
     maps to physical location ``(block_table[pos // bs], pos % bs)``.
@@ -395,9 +396,8 @@ def _paged_write(cache: PagedKVCache, k: jax.Array, v: jax.Array,
                               jnp.clip(col, 0, max_blocks - 1), axis=1)
     blk = jnp.where(col < max_blocks, blk, TRASH_BLOCK)         # (B, S)
     off = positions % bs
-    hk, dh = k.shape[2], k.shape[3]
-    kf = k.astype(cache.k.dtype).reshape(B * S, hk, dh)
-    vf = v.astype(cache.v.dtype).reshape(B * S, hk, dh)
+    kf = k.astype(cache.k.dtype).reshape((B * S,) + cache.k.shape[2:])
+    vf = v.astype(cache.v.dtype).reshape((B * S,) + cache.v.shape[2:])
     kp = cache.k.at[blk.reshape(-1), off.reshape(-1)].set(kf)
     vp = cache.v.at[blk.reshape(-1), off.reshape(-1)].set(vf)
     return PagedKVCache(kp, vp, cache.block_table, cache.length + S)

@@ -2,10 +2,12 @@
 multi-precision matmul so the whole network obeys one PrecisionPolicy."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import dispatch as dispatch_lib
 from repro.core import lanes as lanes_lib
@@ -72,31 +74,61 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: ``0.1 · mscale · ln(factor) + 1``
+    (1 when the context is not stretched)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float,
+                     original_max_pos: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies of a ``dim``-wide rotary part (HF
+    ``DeepseekV2YarnRotaryEmbedding``): pair i keeps its own frequency
+    ``theta^(-2i/dim)`` below the correction range's low end, takes it
+    divided by ``factor`` above its high end, and a linear blend between.
+    The range is where a pair turns between ``beta_fast`` and ``beta_slow``
+    times over ``original_max_pos`` positions."""
+    def turn_dim(rotations):
+        return (dim * math.log(original_max_pos / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turn_dim(beta_fast)), 0)
+    high = min(math.ceil(turn_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(dim // 2, dtype=np.float32)
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    keep = 1.0 - ramp
+    return (extra / factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
-               fraction: float = 1.0) -> jax.Array:
+               fraction: float = 1.0, inv_freq=None,
+               cos_scale: float = 1.0) -> jax.Array:
     """Rotary embedding on the leading ``fraction`` of the head dim.
 
     x: (B, S, H, Dh); positions: (B, S).  fraction=0.5 gives ChatGLM's
-    2D-RoPE layout (first half rotary, second half pass-through)."""
+    2D-RoPE layout (first half rotary, second half pass-through).
+    ``inv_freq`` (rot/2,) replaces the plain ``theta`` frequencies (YaRN),
+    and ``cos_scale`` multiplies cos and sin."""
     dh = x.shape[-1]
     rot = int(dh * fraction)
     rot -= rot % 2
     x_rot, x_pass = x[..., :rot], x[..., rot:]
-    freqs = rope_frequencies(rot, theta)                       # (rot/2,)
+    freqs = (rope_frequencies(rot, theta) if inv_freq is None
+             else jnp.asarray(inv_freq, jnp.float32))          # (rot/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, rot/2)
     cos = jnp.cos(angles)[..., None, :]                        # (B, S, 1, rot/2)
     sin = jnp.sin(angles)[..., None, :]
+    if cos_scale != 1.0:
+        cos, sin = cos * cos_scale, sin * cos_scale
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     r1 = x1 * cos - x2 * sin
     r2 = x2 * cos + x1 * sin
     out = jnp.concatenate([r1, r2], axis=-1).astype(x.dtype)
     return jnp.concatenate([out, x_pass], axis=-1) if rot < dh else out
-
-
-def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
-                           theta: float = 10000.0) -> jax.Array:
-    """DeepSeek-MLA style rope over the dedicated rope dims (full dim)."""
-    return apply_rope(x, positions, theta, fraction=1.0)
 
 
 # --------------------------------------------------------------- init utils

@@ -1,27 +1,41 @@
 """Multi-head Latent Attention (DeepSeek-V2, arXiv:2405.04434) with the
 compressed-latent KV cache and weight-absorbed decode path.
 
+The key/value path projects x down to the latent ``c_kv`` (kv_lora wide),
+normalises it (``kv_a_layernorm``: an RMSNorm with a gain of its own), and
+up-projects it to per-head keys (nope part) and values; a shared rope key of
+qk_rope dims comes straight from x.  RoPE may be YaRN-scaled
+(``MLADims.yarn_factor``), which also raises the softmax scale by the
+squared YaRN temperature.
+
 Training/prefill: standard up-projected attention (latent -> per-head K/V).
-Decode: the cache stores only ``c_kv`` (kv_lora dims) + shared ``k_rope``
-(qk_rope dims) per token — 576 floats/token for DeepSeek-V2 instead of
-2·H·Dh — and the K up-projection is *absorbed* into the query so attention
-runs directly in latent space (the serving optimization from the paper).
+Decode: the cache stores only the normed ``c_kv`` + the rotated ``k_rope``
+per token — 576 floats/token for DeepSeek-V2 instead of 2·H·Dh — and the K
+up-projection is *absorbed* into the query, so attention runs as MQA over
+the latent: ``q_lat[h] = q_nope[h] · W_uk[h]^T``, logits ``q_lat · c_kv +
+q_rope · k_rope``, context ``p · c_kv``, output ``context[h] · W_uv[h]``.
+The dense cache and the serving scheduler's paged latent pool (``k`` holds
+``c_kv``, ``v`` holds ``k_rope``) run the same math.
 
 All projections route through mp_matmul (the framework's reconfigurable
-multiplier), making MLA the flagship consumer of the precision modes.
+multiplier), and both latent attention contractions run at the policy's
+``attn_qk`` / ``attn_pv`` formats.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.mpmatmul import mp_dense, mp_fused_proj
+from repro.core import dispatch as dispatch_lib
+from repro.core.mpmatmul import mp_dense, mp_fused_proj, mp_matmul
 from repro.core.policy import PrecisionPolicy
-from repro.models.attention import NEG_INF, _self_attention
-from repro.models.layers import apply_rope, dense_init
+from repro.models.attention import _paged_write, _self_attention
+from repro.models.layers import (apply_rope, dense_init, rms_norm,
+                                 yarn_frequencies, yarn_mscale)
+from repro.serve.kv_cache import PagedKVCache
 
 
 class MLACache(NamedTuple):
@@ -40,10 +54,43 @@ class MLADims:
     qk_rope_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 10000.0
+    norm_eps: float = 1e-6         # the latent's RMSNorm (kv_a_layernorm)
+    # YaRN (HF ``rope_scaling`` of type "yarn"); factor 0 is plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_dim + self.qk_rope_dim
+
+    def rope_inv_freq(self):
+        """The rope dims' inverse frequencies: YaRN's blend, or None for
+        plain RoPE at ``rope_theta``."""
+        if not self.yarn_factor:
+            return None
+        return yarn_frequencies(self.qk_rope_dim, self.rope_theta,
+                                self.yarn_factor, self.yarn_original_max_pos,
+                                self.yarn_beta_fast, self.yarn_beta_slow)
+
+    @property
+    def rope_cos_scale(self) -> float:
+        if not self.yarn_factor:
+            return 1.0
+        return (yarn_mscale(self.yarn_factor, self.yarn_mscale)
+                / yarn_mscale(self.yarn_factor, self.yarn_mscale_all_dim))
+
+    @property
+    def softmax_scale(self) -> float:
+        """``qk_head_dim^-0.5``, times YaRN's ``mscale(all_dim)^2``."""
+        scale = self.qk_head_dim ** -0.5
+        if self.yarn_factor and self.yarn_mscale_all_dim:
+            scale *= yarn_mscale(self.yarn_factor,
+                                 self.yarn_mscale_all_dim) ** 2
+        return scale
 
 
 def init_mla_params(key, dims: MLADims, dtype=jnp.float32) -> dict:
@@ -52,6 +99,7 @@ def init_mla_params(key, dims: MLADims, dtype=jnp.float32) -> dict:
     p = {
         # KV path: down to latent, up to per-head K(nope)/V
         "w_dkv": dense_init(ks[0], d, dims.kv_lora, dtype),
+        "kv_norm": jnp.ones((dims.kv_lora,), dtype),
         "w_uk": dense_init(ks[1], dims.kv_lora, h * dims.qk_nope_dim, dtype),
         "w_uv": dense_init(ks[2], dims.kv_lora, h * dims.v_head_dim, dtype),
         # decoupled shared rope key (one per token, shared across heads)
@@ -86,6 +134,48 @@ def _input_projections(params, x, dims: MLADims, policy: PrecisionPolicy):
             c_kv, k_rope)
 
 
+def _rope(x: jax.Array, positions: jax.Array, dims: MLADims) -> jax.Array:
+    return apply_rope(x, positions, dims.rope_theta,
+                      inv_freq=dims.rope_inv_freq(),
+                      cos_scale=dims.rope_cos_scale)
+
+
+def head_matrices(w, dims: MLADims, key: str):
+    """The absorbed decode's per-head matrices of a latent up-projection:
+    ``W_uk[h]^T`` (qk_nope, kv_lora) for ``w_uk``, ``W_uv[h]`` (kv_lora,
+    v_head) for ``w_uv``.  A prelimbed params tree stores them so, one limb
+    stack per head (serve/engine.prelimb_dense_params)."""
+    if isinstance(w, (list, tuple)):
+        return w
+    dn = dims.qk_nope_dim if key == "w_uk" else dims.v_head_dim
+    heads = [w[:, h * dn:(h + 1) * dn] for h in range(dims.n_heads)]
+    return [m.T for m in heads] if key == "w_uk" else heads
+
+
+def _per_head(x: jax.Array, ws, mode) -> jax.Array:
+    """x (B, H, K) · ws[h] (K, N) for every head h -> (B, H, N)."""
+    return jnp.stack([mp_matmul(x[:, h], w, mode) for h, w in enumerate(ws)],
+                     axis=1)
+
+
+def _absorbed_decode(params, q_nope, q_rope, attend, dims: MLADims,
+                     policy: PrecisionPolicy) -> jax.Array:
+    """Weight-absorbed single-token decode in latent space, at the
+    policy's formats: the query absorbs ``W_uk`` head by head (qkv),
+    ``attend(q_lat, q_rope)`` runs MQA of the heads over the latent cache
+    (attn_qk / attn_pv) and returns the context (B, H, kv_lora), which
+    ``W_uv`` lifts head by head (qkv) before ``w_o`` (attn_out)."""
+    B = q_nope.shape[0]
+    mode = policy.mode("qkv")
+    q_lat = _per_head(q_nope[:, 0], head_matrices(params["w_uk"], dims,
+                                                  "w_uk"), mode)
+    ctx = attend(q_lat, q_rope[:, 0])
+    out = _per_head(ctx, head_matrices(params["w_uv"], dims, "w_uv"), mode)
+    out = out.reshape(B, 1, dims.n_heads * dims.v_head_dim)
+    return mp_dense(out, params["w_o"], policy.mode("attn_out"),
+                    **policy.bwd_kwargs("attn_out"))
+
+
 def mla_forward(
     params: dict,
     x: jax.Array,
@@ -93,37 +183,58 @@ def mla_forward(
     policy: PrecisionPolicy,
     *,
     positions: Optional[jax.Array] = None,
-    cache: Optional[MLACache] = None,
+    cache=None,
     q_chunk: int = 1024,
     kv_chunk: int = 1024,
-) -> Tuple[jax.Array, Optional[MLACache]]:
+):
+    """MLA block.  ``cache`` is None (training), an :class:`MLACache`
+    (dense decode cache) or a paged latent pool view
+    (:class:`~repro.serve.kv_cache.PagedKVCache`, ``k`` the c_kv pool and
+    ``v`` the k_rope pool).  Returns (out, updated cache or None)."""
     B, S, _ = x.shape
     h = dims.n_heads
     mode, bwd = policy.mode("qkv"), policy.bwd_kwargs("qkv")
+    mode_qk, mode_pv = policy.mode("attn_qk"), policy.mode("attn_pv")
 
     if positions is None:
         base = cache.length if cache is not None else 0
+        base = base[:, None] if getattr(base, "ndim", 0) else base
         positions = jnp.broadcast_to(base + jnp.arange(S)[None, :], (B, S))
 
     q_nope, q_rope, c_kv, k_rope = _input_projections(params, x, dims, policy)
-    q_rope = apply_rope(q_rope, positions, dims.rope_theta)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        dims.rope_theta)[:, :, 0, :]
+    c_kv = rms_norm(c_kv, params["kv_norm"], dims.norm_eps)
+    q_rope = _rope(q_rope, positions, dims)
+    k_rope = _rope(k_rope[:, :, None, :], positions, dims)[:, :, 0, :]
 
     new_cache = None
-    if cache is not None:
+    if isinstance(cache, PagedKVCache):
+        new_cache = _paged_write(cache, c_kv, k_rope, positions)
+        if S == 1:
+            def attend(q_lat, q_r):
+                return dispatch_lib.dispatch_latent_paged_attention(
+                    q_lat, q_r, new_cache.k, new_cache.v,
+                    new_cache.block_table, new_cache.length, mode_qk,
+                    mode_pv, scale=dims.softmax_scale)
+
+            return (_absorbed_decode(params, q_nope, q_rope, attend, dims,
+                                     policy), new_cache)
+        # paged prefill is always into a fresh slot (scheduler invariant),
+        # so attention is plain self-attention over the prompt
+    elif cache is not None:
         ckv = jax.lax.dynamic_update_slice_in_dim(
             cache.c_kv, c_kv.astype(cache.c_kv.dtype), cache.length, axis=1)
         krc = jax.lax.dynamic_update_slice_in_dim(
-            cache.k_rope, k_rope.astype(cache.k_rope.dtype), cache.length, axis=1)
+            cache.k_rope, k_rope.astype(cache.k_rope.dtype), cache.length,
+            axis=1)
         new_cache = MLACache(ckv, krc, cache.length + S)
         if S == 1:
-            out = _absorbed_decode(params, q_nope, q_rope, ckv, krc,
-                                   new_cache.length, dims, policy)
-            out = mp_dense(out.reshape(B, S, h * dims.v_head_dim), params["w_o"],
-                           policy.mode("attn_out"),
-                           **policy.bwd_kwargs("attn_out"))
-            return out, new_cache
+            def attend(q_lat, q_r):
+                return dispatch_lib.masked_latent_attention(
+                    q_lat, q_r, ckv, krc, new_cache.length, mode_qk, mode_pv,
+                    scale=dims.softmax_scale)
+
+            return (_absorbed_decode(params, q_nope, q_rope, attend, dims,
+                                     policy), new_cache)
 
     # train / prefill: up-project latent to per-head K, V (unabsorbed) —
     # both contract c_kv, so they share one fused A decomposition too
@@ -134,6 +245,11 @@ def mla_forward(
     k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :],
                                 (B, S, h, dims.qk_rope_dim))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    # the attention kernels scale by qk_head_dim^-0.5: fold YaRN's
+    # temperature into the query
+    temp = dims.softmax_scale * dims.qk_head_dim ** 0.5
+    if temp != 1.0:
+        q = q * temp
     k = jnp.concatenate([k_nope, k_rope_h], axis=-1)
     # pad V's head dim up to the QK head dim so one attention kernel serves
     # both (values ignore the pad after the contraction)
@@ -149,38 +265,6 @@ def mla_forward(
     out = mp_dense(out, params["w_o"], policy.mode("attn_out"),
                    **policy.bwd_kwargs("attn_out"))
     return out, new_cache
-
-
-def _absorbed_decode(params, q_nope, q_rope, c_kv, k_rope, length,
-                     dims: MLADims, policy: PrecisionPolicy) -> jax.Array:
-    """Weight-absorbed single-token decode in latent space.
-
-    q_lat[h] = q_nope[h] @ W_uk[h]^T  (absorb K up-proj into the query)
-    logits   = q_lat · c_kv + q_rope · k_rope       (T × kv_lora cache only)
-    out[h]   = (p @ c_kv) @ W_uv[h]                 (absorb V up-proj after)
-    """
-    B, S1, h, dn = q_nope.shape
-    lora, dr, dv = dims.kv_lora, dims.qk_rope_dim, dims.v_head_dim
-    mode = policy.mode("attn_logits")
-    w_uk = params["w_uk"].reshape(lora, h, dn)            # (lora, H, dn)
-    # q_lat: absorb — (B,1,H,dn) x (lora,H,dn) -> (B,H,lora)
-    q_lat = jnp.einsum("bshd,lhd->bhl", q_nope.astype(jnp.float32),
-                       w_uk.astype(jnp.float32))
-    scale = 1.0 / jnp.sqrt(dn + dr)
-    T = c_kv.shape[1]
-    ckv = c_kv.astype(jnp.float32)
-    krp = k_rope.astype(jnp.float32)
-    logits = (jnp.einsum("bhl,btl->bht", q_lat, ckv)
-              + jnp.einsum("bshd,btd->bht", q_rope.astype(jnp.float32), krp)
-              ) * scale
-    mask = jnp.arange(T)[None, None, :] < length
-    logits = jnp.where(mask, logits, NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
-    ctx = jnp.einsum("bht,btl->bhl", p, ckv)              # (B, H, lora)
-    w_uv = params["w_uv"].reshape(lora, h, dv)
-    out = jnp.einsum("bhl,lhd->bhd", ctx, w_uv.astype(jnp.float32))
-    del mode  # absorbed einsums run fp32: latent-space is precision-critical
-    return out[:, None, :, :].reshape(B, 1, h, dv)
 
 
 def make_mla_cache(batch: int, max_seq: int, dims: MLADims,

@@ -1,8 +1,13 @@
 """Mixture-of-Experts FFN (DeepSeek-V2 style: shared + routed experts, top-k
 softmax gating) with two dispatch realizations:
 
-``dense``  — every expert runs on every token, gated combine.  Exact (no
-             capacity drops); O(T·E·F) compute.  Smoke tests / tiny models /
+``dense``  — every held expert runs on every token, gated combine (a zero
+             gate where the token did not pick the expert).  Exact (no
+             capacity drops); O(T·E·F) compute.  The layer may hold a share
+             of the experts (``MoEDims.n_held`` from ``first_held``): it
+             routes over all ``n_experts`` and computes its own experts'
+             part of the result, as one chip of an expert-parallel
+             deployment does without the exchange.  Serving, smoke tests,
              oracle for the EP path.
 
 ``ep``     — production expert parallelism: shard_map over (data, model);
@@ -43,18 +48,26 @@ class MoEDims:
     capacity_factor: float = 1.25
     n_chunks: int = 1            # token-chunked dispatch (memory / overlap)
     dispatch_dtype: str = "float32"
+    norm_topk_prob: bool = True  # renormalise the top-k weights to sum 1
+    routed_scaling_factor: float = 1.0
+    n_held: int = 0              # experts this layer holds (0: all of them)
+    first_held: int = 0          # ... from this expert id on
 
     @property
     def shared_ff_dim(self) -> int:
         return self.shared_ff or self.n_shared * self.expert_ff
 
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
 
 def init_moe_params(key, dims: MoEDims, dtype=jnp.float32) -> dict:
     ks = jax.random.split(key, 7)
-    d, e, f = dims.d_model, dims.n_experts, dims.expert_ff
+    d, e, f = dims.d_model, dims.held, dims.expert_ff
     p = {
-        "router": dense_init(ks[0], d, e, dtype, scale=0.02),
-        # stacked expert weights: (E, D, F) / (E, F, D)
+        "router": dense_init(ks[0], d, dims.n_experts, dtype, scale=0.02),
+        # stacked weights of the held experts: (E, D, F) / (E, F, D)
         "w_gate": jax.vmap(lambda k: dense_init(k, d, f, dtype))(
             jax.random.split(ks[1], e)),
         "w_up": jax.vmap(lambda k: dense_init(k, d, f, dtype))(
@@ -74,12 +87,16 @@ def init_moe_params(key, dims: MoEDims, dtype=jnp.float32) -> dict:
 
 def _route(x2d: jax.Array, w_router: jax.Array, dims: MoEDims,
            policy: PrecisionPolicy):
-    """Router: logits -> top-k, renormalized softmax over the chosen k."""
+    """Router: logits -> softmax over all experts in f32 -> greedy top-k;
+    each pick weighs its probability (renormalised over the k when
+    ``norm_topk_prob``) times ``routed_scaling_factor``."""
     logits = mp_matmul(x2d, w_router, policy.mode("moe_router"),
                        **policy.bwd_kwargs("moe_router"))
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top_p, top_i = jax.lax.top_k(probs, dims.top_k)        # (T, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)  # renormalize
+    if dims.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    top_p = top_p * dims.routed_scaling_factor
     # load-balance aux (Switch-style): E * sum_e f_e * P_e
     T = x2d.shape[0]
     me = jnp.mean(probs, axis=0)                            # mean prob per e
@@ -91,29 +108,40 @@ def _route(x2d: jax.Array, w_router: jax.Array, dims: MoEDims,
 
 
 # ----------------------------------------------------------------- dense path
+def held_gates(top_p: jax.Array, top_i: jax.Array, dims: MoEDims
+               ) -> jax.Array:
+    """(T, held) gate of each held expert per token: its routing weight
+    where the token picked it, else 0."""
+    ids = dims.first_held + jnp.arange(dims.held)
+    return jnp.sum(jnp.where(top_i[:, :, None] == ids, top_p[:, :, None],
+                             0.0), axis=1)
+
+
 def moe_forward_dense(params: dict, x: jax.Array, dims: MoEDims,
                       policy: PrecisionPolicy) -> Tuple[jax.Array, dict]:
-    """All-experts-on-all-tokens reference: exact, small-scale only."""
+    """Every held expert on every token, gated: exact, drops no token.
+
+    The expert weights are (E, D, F)-style stacks (mapped over) or, on the
+    serving path, one prelimbed tree per expert (``w_gate`` etc. are lists,
+    each kernel reading its expert's limb stack in place).  The combine is
+    an f32 multiply-add per expert, in expert order."""
     B, S, D = x.shape
     x2 = x.reshape(-1, D)
     top_p, top_i, aux = _route(x2, params["router"], dims, policy)
+    gates = held_gates(top_p, top_i, dims)
+    ws = (params["w_gate"], params["w_up"], params["w_down"])
 
-    mode = policy.mode("moe_expert")
-    bwd = policy.bwd_kwargs("moe_expert")
+    def expert(w):
+        return swiglu_mlp(x2, *w, policy, op_class="moe_expert")
 
-    def expert_fn(wg, wu, wd):
-        g = mp_matmul(x2, wg, mode, **bwd)
-        u = mp_matmul(x2, wu, mode, **bwd)
-        return mp_matmul(jax.nn.silu(g) * u, wd, mode, **bwd)
-
-    all_out = jax.lax.map(
-        lambda w: expert_fn(*w),
-        (params["w_gate"], params["w_up"], params["w_down"]),
-    )  # (E, T, D)
-    gates = jnp.zeros((x2.shape[0], dims.n_experts), jnp.float32)
-    gates = gates.at[jnp.arange(x2.shape[0])[:, None], top_i].set(top_p)
-    out = jnp.einsum("te,etd->td", gates, all_out)
-    out = out.reshape(B, S, D)
+    if isinstance(ws[0], (list, tuple)):
+        outs = [expert(w) for w in zip(*ws)]
+    else:
+        outs = jax.lax.map(expert, ws)                       # (E, T, D)
+    out = jnp.zeros(x2.shape, jnp.float32)
+    for e in range(dims.held):
+        out = out + gates[:, e:e + 1] * outs[e]
+    out = out.reshape(B, S, D).astype(x.dtype)
     out = out + _shared_out(params, x, dims, policy)
     return out, aux
 
@@ -192,6 +220,9 @@ def moe_forward_ep(params: dict, x: jax.Array, dims: MoEDims,
     (no slice, no output all_gather)."""
     from jax.sharding import PartitionSpec as P
 
+    if dims.held != dims.n_experts:
+        raise ValueError("the EP path holds every expert across the mesh; "
+                         "a layer holding a share runs moe_forward_dense")
     B, S, D = x.shape
     E = dims.n_experts
     m_size = mesh.shape[model_axis]

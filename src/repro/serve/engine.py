@@ -45,25 +45,36 @@ from repro.core.lanes import LaneCtx, LaneEnvelope, lane_scope
 from repro.core.limbs import PrelimbedWeight, prelimb_weight
 from repro.core.policy import PrecisionPolicy
 from repro.models import transformer as T
+from repro.models.mla import head_matrices
 from repro.serve.kv_cache import PagedKVCache
 from repro.train.trainer import make_prefill_step, make_serve_step
 
-# op classes whose weights sit on the decode dense path (the pre-limb set);
-# MoE experts/MLA stay raw: their weights reshape/absorb before contracting
+# op classes whose weights sit on the decode path (the pre-limb set); a
+# model with routed experts adds _MOE_CLASSES
 _PRELIMB_CLASSES = ("qkv", "attn_out", "ffn", "lm_head")
+_MOE_CLASSES = ("moe_router", "moe_expert")
 
-# params-tree groups -> weight leaves that feed mp_dense 1:1 (safe to carry
-# as limb stacks; anything that is reshaped, LoRA-patched, or einsum'd —
-# MLA, MoE, SSM, the hybrid shared block — is deliberately absent)
+# params-tree groups -> weight leaves read as whole matrices (one limb stack
+# each); anything reshaped, LoRA-patched or scanned inside a layer — SSM,
+# the hybrid shared block — is deliberately absent
 _PRELIMB_LEAVES = {"mlp": ("w_gate", "w_up", "w_down"),
-                   "attn": ("wq", "wk", "wv", "wo")}
+                   "attn": ("wq", "wk", "wv", "wo"),
+                   "mla": ("w_q", "w_dq", "w_uq", "w_dkv", "w_kr", "w_o"),
+                   "moe": ("router",),
+                   "shared": ("w_gate", "w_up", "w_down")}
+# (E, K, N) stacks of held experts: one limb stack per expert
+_EXPERT_LEAVES = {"moe": ("w_gate", "w_up", "w_down")}
+# MLA's latent up-projections: one limb stack per head, as the absorbed
+# decode reads them (models/mla.head_matrices)
+_HEAD_LEAVES = {"mla": ("w_uk", "w_uv")}
 
 
-def _policy_prelimb_limbs(policy: PrecisionPolicy) -> Optional[int]:
+def _policy_prelimb_limbs(policy: PrecisionPolicy,
+                          classes=_PRELIMB_CLASSES) -> Optional[int]:
     """Max limb count any decode-path forward format needs, or None when an
     AUTO rule makes pre-limbing unusable (AUTO analyzes raw values)."""
     n = 1
-    for c in _PRELIMB_CLASSES:
+    for c in classes:
         mode = policy.mode(c)
         if is_auto(mode):
             return None
@@ -79,53 +90,74 @@ def _prelimb_padded(w, n_limbs: int) -> PrelimbedWeight:
                           padded=prelimbed_storage_shape(*w.shape[-2:]))
 
 
-def _prelimb_stack(blocks: dict, n_limbs: int):
-    """Prelimb one layer-stacked params group.
+def _leaf_kind(group, key) -> Optional[str]:
+    if key in _EXPERT_LEAVES.get(group, ()):
+        return "experts"
+    if key in _HEAD_LEAVES.get(group, ()):
+        return "heads"
+    if key in _PRELIMB_LEAVES.get(group, ()):
+        return "matrix"
+    return None
 
-    When every leaf outside the prelimb set is a per-layer vector (norm
-    gains: the dense GQA stack), the stack comes back as a list of
-    per-layer trees, each limb stack its own (n_limbs, K', N') buffer,
-    prelimbed layer by layer so the stacked and split stacks are never held
-    at once.  Otherwise (MLA or MoE layers, whose raw matrices a split would
-    copy) it stays stacked, with (L, n_limbs, K', N') limb stacks."""
-    pre = {(g, k) for g, keys in _PRELIMB_LEAVES.items()
-           if isinstance(blocks.get(g), dict) for k in keys if k in blocks[g]}
-    rest = [x for g, sub in blocks.items()
-            for k, x in (sub.items() if isinstance(sub, dict) else [(0, sub)])
-            if (g, k) not in pre]
-    split = bool(pre) and all(x.ndim <= 2
-                              for x in jax.tree_util.tree_leaves(rest))
 
-    def layer(pick):
-        return {g: ({k: (_prelimb_padded(pick(x), n_limbs) if (g, k) in pre
-                         else jax.tree_util.tree_map(pick, x))
-                     for k, x in sub.items()} if isinstance(sub, dict)
-                    else jax.tree_util.tree_map(pick, sub))
-                for g, sub in blocks.items()}
+def _walk(tree, fn, group=None):
+    """``fn(group, key, leaf)`` over a nested dict of arrays."""
+    return {k: (_walk(v, fn, k) if isinstance(v, dict) else fn(group, k, v))
+            for k, v in tree.items()}
 
-    if not split:
-        return layer(lambda x: x)
+
+def _prelimb_stack(blocks: dict, n_limbs: int, mla=None):
+    """Prelimb one layer-stacked params group into a list of per-layer
+    trees, each limb stack its own (n_limbs, K', N') buffer, prelimbed
+    layer by layer so the stacked and split stacks are never held at once.
+    Expert stacks become a list of per-expert stacks, MLA's latent
+    up-projections a list of per-head ones (``mla`` gives the heads).
+
+    A stack is split only where every leaf left raw is a per-layer vector
+    (norm gains); otherwise (SSM or hybrid layers, whose raw matrices a
+    split would copy) it is returned unchanged."""
+    kinds = []
+    _walk(blocks, lambda g, k, x: kinds.append((_leaf_kind(g, k), x.ndim)))
+    if not any(kind for kind, _ in kinds) or any(
+            kind is None and nd > 2 for kind, nd in kinds):
+        return blocks
+
+    def one(i):
+        def leaf(group, key, x):
+            x = x[i]
+            kind = _leaf_kind(group, key)
+            if kind == "experts":
+                return [_prelimb_padded(w, n_limbs) for w in x]
+            if kind == "heads":
+                return [_prelimb_padded(w, n_limbs)
+                        for w in head_matrices(x, mla, key)]
+            return _prelimb_padded(x, n_limbs) if kind else x
+
+        return _walk(blocks, leaf)
+
     n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-    return [layer(lambda x, i=i: x[i]) for i in range(n)]
+    return [one(i) for i in range(n)]
 
 
-def prelimb_dense_params(params, n_limbs: int):
-    """Decompose the dense-path weight matrices of a transformer params tree
-    into :class:`PrelimbedWeight` limb stacks (one-time, per policy), with
-    the jnp cascade ``limbs.prelimb_weight`` on every backend.
+def prelimb_dense_params(params, n_limbs: int, mla=None):
+    """Decompose the decode-path weight matrices of a transformer params
+    tree into :class:`PrelimbedWeight` limb stacks (one-time, per policy),
+    with the jnp cascade ``limbs.prelimb_weight`` on every backend.
 
     Each stack is stored zero-padded to the kernel's block multiples
     (``ops.prelimbed_storage_shape``), so the decode step pads no weight.
-    A dense layer stack comes back as a list of per-layer trees
-    (``_prelimb_stack``): the forward then walks the layers in Python and
-    each kernel reads its layer's stack in place, where a ``lax.scan``
-    would copy every layer's slice out of the stacked weights on every
-    decode step.  Non-dict / absent groups pass through untouched.
+    A layer stack comes back as a list of per-layer trees
+    (``_prelimb_stack``; ``mla``, the model's ``MLADims``, splits MLA's
+    latent up-projections per head): the forward then walks the layers in
+    Python and each kernel reads its layer's (expert's, head's) stack in
+    place, where a ``lax.scan`` would copy every layer's slice out of the
+    stacked weights on every decode step.  Non-dict / absent groups pass
+    through untouched.
     """
     out = dict(params)
     for stack_key in ("layers", "dense_layers"):
         if isinstance(out.get(stack_key), dict):
-            out[stack_key] = _prelimb_stack(out[stack_key], n_limbs)
+            out[stack_key] = _prelimb_stack(out[stack_key], n_limbs, mla)
     for head in ("lm_head", "ctc_head"):
         if isinstance(out.get(head), dict) and "w" in out[head]:
             out[head] = {**out[head],
@@ -144,6 +176,18 @@ def prelimb_pad_bytes(tree) -> int:
     return total
 
 
+def expert_limb_bytes(tree) -> int:
+    """Bytes of the held experts' limb stacks in a prelimbed tree."""
+    total = 0
+    for stack in (tree.get("layers"), tree.get("dense_layers")):
+        for layer in stack if isinstance(stack, list) else ():
+            moe = layer.get("moe", {})
+            for key in _EXPERT_LEAVES["moe"]:
+                total += sum(w.limbs.nbytes for w in moe.get(key, ())
+                             if isinstance(w, PrelimbedWeight))
+    return total
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -151,6 +195,32 @@ class Request:
     max_new: int = 16
     out: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+
+
+def _paged_cache(cfg: ModelConfig, pool_k, pool_v, table, lengths
+                 ) -> T.ModelCache:
+    """The model's cache over the paged pool: every layer's view of the
+    pool with the slots' block table and lengths.  An MoE stack's leading
+    dense layers hold the pool's first layers (``dense_attn``)."""
+    def view(k, v):
+        n = k.shape[0]
+        return PagedKVCache(
+            k=k, v=v, block_table=jnp.broadcast_to(table, (n,) + table.shape),
+            length=jnp.broadcast_to(lengths, (n,) + lengths.shape))
+
+    if cfg.family == "moe" and cfg.first_k_dense:
+        f = cfg.first_k_dense
+        return T.ModelCache(attn=view(pool_k[f:], pool_v[f:]),
+                            dense_attn=view(pool_k[:f], pool_v[:f]))
+    return T.ModelCache(attn=view(pool_k, pool_v))
+
+
+def _new_pools(cache: T.ModelCache):
+    """The step's new (k, v) pools, layers in the pool's order."""
+    if cache.dense_attn is None:
+        return cache.attn.k, cache.attn.v
+    return (jnp.concatenate([cache.dense_attn.k, cache.attn.k]),
+            jnp.concatenate([cache.dense_attn.v, cache.attn.v]))
 
 
 def make_paged_prefill_step(cfg: ModelConfig, policy: PrecisionPolicy,
@@ -170,18 +240,13 @@ def make_paged_prefill_step(cfg: ModelConfig, policy: PrecisionPolicy,
     non-finite stat); computing it inside the step keeps the check free of
     extra launches.
     """
-    L = cfg.n_layers
-
     def step(params, pool_k, pool_v, table, lengths, tokens, last_idx):
-        cache = T.ModelCache(attn=PagedKVCache(
-            k=pool_k, v=pool_v,
-            block_table=jnp.broadcast_to(table, (L,) + table.shape),
-            length=jnp.broadcast_to(lengths, (L,) + lengths.shape)))
+        cache = _paged_cache(cfg, pool_k, pool_v, table, lengths)
         logits, _, new_cache = T.forward(params, {"tokens": tokens}, cfg,
                                          policy, cache=cache, mesh=mesh)
         last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1, axis=1)
         stat = jnp.max(jnp.abs(last[:, 0, :]), axis=-1)
-        return last, stat, new_cache.attn.k, new_cache.attn.v
+        return (last, stat) + _new_pools(new_cache)
 
     return step
 
@@ -198,17 +263,12 @@ def make_paged_decode_step(cfg: ModelConfig, policy: PrecisionPolicy,
     guardrail polices — folded into the step so the finite check costs one
     scalar per slot and no extra launch.
     """
-    L = cfg.n_layers
-
     def step(params, pool_k, pool_v, table, lengths, tokens):
-        cache = T.ModelCache(attn=PagedKVCache(
-            k=pool_k, v=pool_v,
-            block_table=jnp.broadcast_to(table, (L,) + table.shape),
-            length=jnp.broadcast_to(lengths, (L,) + lengths.shape)))
+        cache = _paged_cache(cfg, pool_k, pool_v, table, lengths)
         logits, _, new_cache = T.forward(params, {"tokens": tokens}, cfg,
                                          policy, cache=cache, mesh=mesh)
         stat = jnp.max(jnp.abs(logits[:, -1, :]), axis=-1)
-        return logits, stat, new_cache.attn.k, new_cache.attn.v
+        return (logits, stat) + _new_pools(new_cache)
 
     return step
 
@@ -233,24 +293,41 @@ def make_mixed_decode_step(cfg: ModelConfig, envelope: LaneEnvelope,
     reads the lane tables instead.  Same (logits, guard_stat, pools) return
     contract as :func:`make_paged_decode_step`.
     """
-    L = cfg.n_layers
     carrier = PrecisionPolicy.serve_default()
 
     def step(params, pool_k, pool_v, table, lengths, tokens, lane_n,
              lane_ord):
-        cache = T.ModelCache(attn=PagedKVCache(
-            k=pool_k, v=pool_v,
-            block_table=jnp.broadcast_to(table, (L,) + table.shape),
-            length=jnp.broadcast_to(lengths, (L,) + lengths.shape)))
+        cache = _paged_cache(cfg, pool_k, pool_v, table, lengths)
         ctx = LaneCtx(envelope, lane_n.astype(jnp.int32),
                       lane_ord.astype(jnp.int32))
         with lane_scope(ctx):
             logits, _, new_cache = T.forward(params, {"tokens": tokens}, cfg,
                                              carrier, cache=cache, mesh=mesh)
         stat = jnp.max(jnp.abs(logits[:, -1, :]), axis=-1)
-        return logits, stat, new_cache.attn.k, new_cache.attn.v
+        return (logits, stat) + _new_pools(new_cache)
 
     return step
+
+
+def paged_model_error(cfg: ModelConfig) -> Optional[str]:
+    """Why the paged serving path cannot run ``cfg``, or None: it serves
+    dense GQA stacks, and MoE stacks with latent attention (MLA)."""
+    if cfg.family == "dense" and cfg.mla is None:
+        return None
+    if cfg.family == "moe" and cfg.mla is not None:
+        return None
+    return (f"paged serving runs dense GQA models and MoE models with "
+            f"latent attention (family={cfg.family!r}, "
+            f"mla={cfg.mla is not None})")
+
+
+def pool_entry_shapes(cfg: ModelConfig):
+    """Each token's (k, v) entry in one layer of the paged pool: the GQA
+    heads' keys and values, or MLA's latent ``c_kv`` and rope key."""
+    if cfg.mla is not None:
+        return (cfg.mla.kv_lora,), (cfg.mla.qk_rope_dim,)
+    kv = (cfg.n_kv_heads, cfg.resolved_head_dim)
+    return kv, kv
 
 
 class ServeEngine:
@@ -360,12 +437,11 @@ class ServeEngine:
         The continuous scheduler resolves a policy *per request* and buckets
         compatible requests per decode micro-batch; this cache is what makes
         a working set of per-request modes free after the first trace (same
-        LRU discipline as :meth:`_steps_for`).  Paged serving assumes the
-        dense GQA cache layout."""
-        if self.cfg.family not in ("dense",) or self.cfg.mla is not None:
-            raise NotImplementedError(
-                f"paged serving supports dense GQA models only "
-                f"(family={self.cfg.family!r}, mla={self.cfg.mla is not None})")
+        LRU discipline as :meth:`_steps_for`).  Paged serving runs the
+        models :func:`paged_model_error` admits."""
+        err = paged_model_error(self.cfg)
+        if err is not None:
+            raise NotImplementedError(err)
         return self._cached_steps(
             self._paged_step_cache, policy,
             (make_paged_prefill_step, make_paged_decode_step))
@@ -377,12 +453,16 @@ class ServeEngine:
         that fits under it (any per-slot assignment of formats at or below
         the per-class ceilings) shares one compiled executable.  A mode
         joining mid-stream therefore reuses the batch-max trace instead of
-        minting (and possibly evicting) per-policy entries.  Same dense-GQA
-        restriction as :meth:`paged_steps_for`."""
-        if self.cfg.family not in ("dense",) or self.cfg.mla is not None:
+        minting (and possibly evicting) per-policy entries.  Dense GQA
+        models only: latent attention and routed experts have no lane
+        kernels, so a batch of mixed formats on them cannot share one
+        launch."""
+        if self.cfg.family != "dense" or self.cfg.mla is not None:
             raise NotImplementedError(
-                f"paged serving supports dense GQA models only "
-                f"(family={self.cfg.family!r}, mla={self.cfg.mla is not None})")
+                f"mixed-format decode lanes run dense GQA models only; "
+                f"latent attention and routed experts have no lane kernels "
+                f"(family={self.cfg.family!r}, "
+                f"mla={self.cfg.mla is not None})")
         return self._cached_steps(self._mixed_step_cache, envelope,
                                   (make_mixed_decode_step,))[0]
 
@@ -408,7 +488,9 @@ class ServeEngine:
         stacks, decomposed ONCE per (policy limb count, params) and cached.
         Falls back to the raw params under AUTO policies or when pre-limbing
         is disabled."""
-        return self._decode_params_for_limbs(_policy_prelimb_limbs(policy))
+        classes = _PRELIMB_CLASSES + (_MOE_CLASSES if self.cfg.moe else ())
+        return self._decode_params_for_limbs(
+            _policy_prelimb_limbs(policy, classes))
 
     def _decode_params_for_limbs(self, n: Optional[int]):
         """Pre-limbed decode params at an explicit limb depth — the entry
@@ -430,7 +512,8 @@ class ServeEngine:
             stale = [k for k in self._prelimb_cache if k[1] != id(self.params)]
             for k in stale:
                 del self._prelimb_cache[k]
-            self._prelimb_cache[key] = prelimb_dense_params(self.params, n)
+            self._prelimb_cache[key] = prelimb_dense_params(
+                self.params, n, mla=self.cfg.mla)
         return self._prelimb_cache[key]
 
     def cache_stats(self) -> Dict[str, int]:
@@ -438,7 +521,11 @@ class ServeEngine:
         ``stats()``): ``trace_events`` counts jit traces actually executed;
         the hit/miss pairs cover the jit'd-step LRU and the prelimbed-weight
         cache; ``prelimb_pad_bytes`` is the memory the cached limb stacks
-        spend on zero padding to the kernel's blocks."""
+        spend on zero padding to the kernel's blocks;
+        ``expert_limb_bytes`` the cached limb stacks of the held experts,
+        and ``experts_held`` how many experts each MoE layer holds (0 for
+        a model without routed experts)."""
+        moe = self.cfg.moe
         return {
             "trace_events": self.trace_events,
             "step_cache_hits": self.step_cache_hits,
@@ -447,6 +534,9 @@ class ServeEngine:
             "prelimb_cache_misses": self.prelimb_cache_misses,
             "prelimb_pad_bytes": sum(prelimb_pad_bytes(t) for t
                                      in self._prelimb_cache.values()),
+            "expert_limb_bytes": sum(expert_limb_bytes(t) for t
+                                     in self._prelimb_cache.values()),
+            "experts_held": moe.held if moe is not None else 0,
         }
 
     # -- single-request path (prefill writes the whole pool cache; simple and

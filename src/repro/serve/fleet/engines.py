@@ -36,7 +36,7 @@ from typing import Deque, List, Optional, Tuple
 import jax.numpy as jnp
 
 from repro.serve import primitives as prim
-from repro.serve.engine import ServeEngine
+from repro.serve.engine import ServeEngine, pool_entry_shapes
 from repro.serve.faults import CellCrashed, FaultInjector
 from repro.serve.fleet.handoff import KVHandoff, deliver
 from repro.serve.kv_cache import PagedKVPool
@@ -227,9 +227,8 @@ class FleetCell:
             max_blocks_per_seq = max(1, -(-engine.max_seq // block_size))
         self.cell_id = cell_id
         self.pool = PagedKVPool(
-            cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads,
-            cfg.resolved_head_dim, max_blocks_per_seq=max_blocks_per_seq,
-            dtype=jnp.float32)
+            cfg.n_layers, n_blocks, block_size, *pool_entry_shapes(cfg),
+            max_blocks_per_seq=max_blocks_per_seq, dtype=jnp.float32)
         self.prefill = PrefillEngine(
             engine, self.pool, cell_id=cell_id,
             max_prefills_per_tick=1 if disaggregate else 0)

@@ -10,9 +10,13 @@ that hands blocks to requests and reclaims them on eviction, and per-request
 **block tables** mapping logical token positions to physical blocks — the
 vLLM paged-attention memory model, sized for this repo's CPU/TPU test scale.
 
-Layout (one pool array per K and V):
+Layout (one pool array per K and V, each with an entry shape of its own):
 
-    k, v: (n_layers, n_blocks, block_size, n_kv_heads, head_dim)
+    k, v: (n_layers, n_blocks, block_size) + entry shape
+
+GQA attention stores ``(n_kv_heads, head_dim)`` in both; latent attention
+(MLA) stores one head's latent ``c_kv`` (``(kv_lora,)``) in ``k`` and its
+rotated rope key (``(qk_rope,)``) in ``v``.
 
 Logical position ``p`` of a request lives at ``pool[layer, table[p // bs],
 p % bs]`` where ``table`` is the request's block-table row.  Block 0 is the
@@ -55,14 +59,15 @@ class PagedKVCache(NamedTuple):
     so they ride the layer scan like any stacked cache leaf).
     """
 
-    k: jax.Array            # (n_blocks, block_size, Hkv, Dh)
-    v: jax.Array            # (n_blocks, block_size, Hkv, Dh)
+    k: jax.Array            # (n_blocks, block_size) + k entry shape
+    v: jax.Array            # (n_blocks, block_size) + v entry shape
     block_table: jax.Array  # (B, max_blocks) int32 physical block ids
     length: jax.Array       # (B,) int32 valid prefix length per slot
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[-3]
+        """Positions per block (of the per-layer view)."""
+        return self.k.shape[1]
 
 
 class BlockPoolExhausted(RuntimeError):
@@ -87,8 +92,11 @@ class PagedKVPool:
     """
 
     def __init__(self, n_layers: int, n_blocks: int, block_size: int,
-                 n_kv_heads: int, head_dim: int, *,
+                 k_shape: Sequence[int], v_shape: Sequence[int], *,
                  max_blocks_per_seq: int, dtype=jnp.float32):
+        """``k_shape`` / ``v_shape``: each token's entry in one layer —
+        ``(n_kv_heads, head_dim)`` for GQA, ``(kv_lora,)`` and
+        ``(qk_rope,)`` for a latent pool."""
         if n_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is reserved trash)")
         if block_size < 1 or max_blocks_per_seq < 1:
@@ -97,9 +105,9 @@ class PagedKVPool:
         self.n_blocks = n_blocks
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
-        shape = (n_layers, n_blocks, block_size, n_kv_heads, head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        lead = (n_layers, n_blocks, block_size)
+        self.k = jnp.zeros(lead + tuple(k_shape), dtype)
+        self.v = jnp.zeros(lead + tuple(v_shape), dtype)
         self._free: List[int] = list(range(1, n_blocks))  # LIFO reuse
         self._live: set = set()
         self._lock = threading.Lock()
@@ -189,7 +197,9 @@ class PagedKVPool:
             raise ValueError(
                 f"block count mismatch: {len(src_blocks)} src vs "
                 f"{len(dst_blocks)} dst")
-        if self.k.shape[2:] != dst.k.shape[2:] or self.n_layers != dst.n_layers:
+        if (self.k.shape[2:] != dst.k.shape[2:]
+                or self.v.shape[2:] != dst.v.shape[2:]
+                or self.n_layers != dst.n_layers):
             raise ValueError(
                 f"incompatible pool geometry: {self.k.shape} vs {dst.k.shape}")
         si = jnp.asarray(src_blocks, jnp.int32)
@@ -203,6 +213,11 @@ class PagedKVPool:
             # responsible for catching it downstream
             dst.k = dst.k.at[:, di[0]].set(jnp.nan)
             dst.v = dst.v.at[:, di[0]].set(jnp.nan)
+
+    @property
+    def nbytes(self) -> int:
+        """Device bytes of the pool arrays."""
+        return self.k.nbytes + self.v.nbytes
 
     # ---- jit-side pool hand-back ------------------------------------------
     def update(self, k: jax.Array, v: jax.Array) -> None:

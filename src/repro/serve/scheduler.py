@@ -54,7 +54,8 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
 from repro.serve import primitives as prim
-from repro.serve.engine import ServeEngine
+from repro.serve.engine import (ServeEngine, paged_model_error,
+                                pool_entry_shapes)
 from repro.serve.faults import FaultInjector
 from repro.serve.kv_cache import BlockPoolExhausted, PagedKVPool
 from repro.serve.primitives import (  # re-export  # noqa: F401
@@ -82,17 +83,16 @@ class ContinuousScheduler:
                  max_blocks_per_seq: Optional[int] = None,
                  guard: Optional[GuardrailConfig] = None):
         cfg = engine.cfg
-        if cfg.family not in ("dense",) or cfg.mla is not None:
-            raise NotImplementedError(
-                "continuous scheduling supports dense GQA models only")
+        err = paged_model_error(cfg)
+        if err is not None:
+            raise NotImplementedError(err)
         self.engine = engine
         if max_blocks_per_seq is None:
             max_blocks_per_seq = max(
                 1, -(-engine.max_seq // block_size))
         self.pool = PagedKVPool(
-            cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads,
-            cfg.resolved_head_dim, max_blocks_per_seq=max_blocks_per_seq,
-            dtype=jnp.float32)
+            cfg.n_layers, n_blocks, block_size, *pool_entry_shapes(cfg),
+            max_blocks_per_seq=max_blocks_per_seq, dtype=jnp.float32)
         self.max_slots = engine.max_batch
         self._slots: List[Optional[ScheduledRequest]] = [None] * self.max_slots
         self._queue: Deque[ScheduledRequest] = deque()
@@ -363,6 +363,8 @@ class ContinuousScheduler:
                    self.decode_launches / self.decode_ticks, 4)
                if self.decode_ticks else 0.0}
         out.update(self.engine.cache_stats())
+        out["latent_pool_bytes"] = (self.pool.nbytes
+                                    if self.engine.cfg.mla is not None else 0)
         if self.injector is not None:
             out.update(self.injector.stats())
         out.update(prim.latency_stats(self.completed))

@@ -24,6 +24,7 @@ from repro.serve.kv_cache import BlockPoolExhausted, PagedKVPool
 from repro.serve.scheduler import ContinuousScheduler, ScheduledRequest
 
 CFG = get_config("paper-mpfp-100m", smoke=True)
+KV = (CFG.n_kv_heads, CFG.resolved_head_dim)   # a pool entry: K and V alike
 
 
 @pytest.fixture(scope="module")
@@ -106,8 +107,8 @@ class TestHandoffParity:
 # =========================================================================
 class TestCrossPoolHandoff:
     def _pool(self, n_blocks=8):
-        return PagedKVPool(2, n_blocks, 4, CFG.n_kv_heads,
-                           CFG.resolved_head_dim, max_blocks_per_seq=4)
+        return PagedKVPool(2, n_blocks, 4, KV, KV,
+                            max_blocks_per_seq=4)
 
     def test_transfer_blocks_bit_identical(self):
         src, dst = self._pool(), self._pool()
@@ -124,8 +125,8 @@ class TestCrossPoolHandoff:
 
     def test_transfer_rejects_geometry_mismatch(self):
         src = self._pool()
-        odd = PagedKVPool(2, 8, 2, CFG.n_kv_heads,
-                          CFG.resolved_head_dim, max_blocks_per_seq=4)
+        odd = PagedKVPool(2, 8, 2, KV, KV,
+                           max_blocks_per_seq=4)
         with pytest.raises(ValueError):
             src.transfer_blocks(odd, [1], [1])
 
@@ -198,8 +199,7 @@ class TestCrossPoolHandoff:
 # =========================================================================
 class TestPoolNegativePaths:
     def _pool(self, n_blocks=8, max_per_seq=4):
-        return PagedKVPool(2, n_blocks, 4, CFG.n_kv_heads,
-                           CFG.resolved_head_dim,
+        return PagedKVPool(2, n_blocks, 4, KV, KV,
                            max_blocks_per_seq=max_per_seq)
 
     def test_double_free_and_foreign_free_rejected(self):

@@ -331,7 +331,7 @@ class TestBoundedGather:
         from repro.serve.kv_cache import PagedKVPool
         from repro.serve.primitives import table_width
 
-        pool = PagedKVPool(1, 32, 4, 2, 8, max_blocks_per_seq=16)
+        pool = PagedKVPool(1, 32, 4, (2, 8), (2, 8), max_blocks_per_seq=16)
         assert table_width(pool, [_R(1)]) == 1
         assert table_width(pool, [_R(3), _R(5)]) == 8
         assert table_width(pool, [_R(16)]) == 16  # clamped to capacity

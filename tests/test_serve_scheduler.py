@@ -18,6 +18,7 @@ from repro.serve.kv_cache import (
 from repro.serve.scheduler import ContinuousScheduler, ScheduledRequest
 
 CFG = get_config("paper-mpfp-100m", smoke=True)
+KV = (CFG.n_kv_heads, CFG.resolved_head_dim)   # a pool entry: K and V alike
 
 
 @pytest.fixture(scope="module")
@@ -42,8 +43,8 @@ def _prompts(seed, sizes):
 # =========================================================================
 class TestPagedPool:
     def _pool(self, n_blocks=8):
-        return PagedKVPool(2, n_blocks, 4, CFG.n_kv_heads,
-                           CFG.resolved_head_dim, max_blocks_per_seq=4)
+        return PagedKVPool(2, n_blocks, 4, KV, KV,
+                            max_blocks_per_seq=4)
 
     def test_never_double_allocates(self):
         pool = self._pool()

@@ -1,7 +1,8 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e at
 the paper model's widths (``paper-mpfp-100m``: d_model 768, 12 heads of 64,
 d_ff 3072, vocab 32000), on a described ``v5e:2x2`` topology — no chip; and
-of the paged decode step at ChatGLM3-6B's widths, the benchmark's model.
+of the paged decode step at the widths of the benchmark's models,
+ChatGLM3-6B and DeepSeek-V2-Lite.
 
 Interpret mode accepts kernels that Mosaic refuses (grid / dimension-
 semantics mismatches, unaligned tiles, VMEM overruns); these compiles catch
@@ -29,6 +30,8 @@ from repro.core.limbs import PrelimbedWeight
 from repro.core.policy import PrecisionPolicy
 from repro.kernels import mp_attention, ops
 from repro.models import transformer as T
+from repro.models.mla import MLADims
+from repro.models.moe import MoEDims
 from repro.serve.engine import make_paged_decode_step, prelimb_dense_params
 
 CFG = get_config("paper-mpfp-100m")
@@ -193,3 +196,75 @@ def test_paged_decode_reads_limb_stacks_in_place(one_chip, monkeypatch):
     patterns = json.loads(MM_FAMILY.read_text())["patterns"]
     for line in calls:
         assert any(re.search(p, line) for p in patterns), line[:200]
+
+
+def test_latent_paged_attention(one_chip):
+    """DeepSeek-V2-Lite's latent pool: 16 heads over c_kv 512 + rope 64,
+    blocks of 64 positions."""
+    _compiled_text(
+        lambda ql, qr, c, r, t, ln: mp_attention.mp_latent_paged_attention_pallas(
+            ql, qr, c, r, t, ln, "M23", "M23", scale=0.115),
+        one_chip, ((64, 16, 512), F32), ((64, 16, 64), F32),
+        ((129, 64, 512), F32), ((129, 64, 64), F32), ((64, 40), I32),
+        ((64,), I32), kernel="mp_latent_paged_attention")
+
+
+# DeepSeek-V2-Lite at its published widths (bench/configs/
+# deepseek-v2-lite-5l.json), its dense layer and one MoE layer holding 8 of
+# its 64 experts
+DSV2 = ModelConfig(
+    name="deepseek-v2-lite-2l", family="moe", n_layers=2, d_model=2048,
+    vocab=102400, n_heads=16, n_kv_heads=16, d_ff=1408, norm_eps=1e-6,
+    first_k_dense=1, dense_ff=10944, max_seq=2560,
+    mla=MLADims(d_model=2048, n_heads=16, kv_lora=512, qk_nope_dim=128,
+                qk_rope_dim=64, v_head_dim=128, yarn_factor=40.0,
+                yarn_mscale=0.707, yarn_mscale_all_dim=0.707),
+    moe=MoEDims(d_model=2048, n_experts=64, top_k=6, expert_ff=1408,
+                n_shared=2, norm_topk_prob=False, n_held=8))
+FAMILIES = Path(__file__).resolve().parents[1] / "bench" / "kernels"
+
+
+def test_moe_mla_paged_decode_reads_limb_stacks_in_place(one_chip,
+                                                         monkeypatch):
+    """The MoE + MLA paged decode step on prelimbed M23 weights: no ``pad``
+    or ``dynamic-slice`` makes a bf16 limb stack; every projection, head,
+    expert and the lm_head runs the prelimbed kernel on its own stored
+    stack; the latent kernel runs once a layer; and the benchmark's kernel
+    families find the held experts' matmuls and the latent kernel."""
+    monkeypatch.setattr(dispatch, "kernel_interpret", lambda backend: False)
+    policy = PrecisionPolicy.serve_default().overlay("M23")
+    put = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    raw = jax.eval_shape(lambda k: T.init_params(DSV2, k),
+                         jax.random.PRNGKey(0))
+    params = put(jax.eval_shape(
+        lambda p: prelimb_dense_params(p, 3, mla=DSV2.mla), raw))
+    slots, blocks, bs = 64, 129, 64
+    shapes = [((2, blocks, bs, 512), F32), ((2, blocks, bs, 64), F32),
+              ((slots, 8), I32), ((slots,), I32), ((slots, 1), I32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    step = dispatch.pin_backend(make_paged_decode_step(DSV2, policy), "pallas")
+    text = jax.jit(step).lower(params, *args).compile().as_text()
+
+    copies = re.findall(r"= bf16\[\d+,\d+,\d+\]\S* (?:pad|dynamic-slice)\(|"
+                        r"%(?:pad|dynamic-slice)\S*fusion\S* = bf16\[\d+,\d+,\d+\]",
+                        text)
+    assert not copies, copies
+    calls = [line for line in text.splitlines()
+             if re.search(r"%mp_matmul_prelimbed(\.\d+)? = .* custom-call\(",
+                          line)]
+    # per layer: w_q, w_dkv, w_kr, 16 + 16 heads, w_o; the dense layer's
+    # gate, up, down; the MoE layer's router, 3 shared, 8 x 3 experts; head
+    assert len(calls) == 2 * 36 + 3 + 1 + 3 + 24 + 1
+    latent = [line for line in text.splitlines() if re.search(
+        r"%mp_latent_paged_attention(\.\d+)? = .* custom-call\(", line)]
+    assert len(latent) == DSV2.n_layers
+    fams = {f: [re.compile(p) for p in json.loads(
+        (FAMILIES / f"{f}.json").read_text())["patterns"]]
+        for f in ("moe_expert", "mla_attention")}
+    hits = lambda f, lines: [x for x in lines
+                             if any(p.search(x) for p in fams[f])]
+    assert len(hits("moe_expert", calls)) == 24
+    assert hits("mla_attention", latent) == latent
+    assert not hits("mla_attention", calls)
