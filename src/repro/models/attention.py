@@ -374,33 +374,34 @@ def _decode_attention(q, k_cache, v_cache, length, dims: AttnDims,
 
 def _paged_write(cache: PagedKVCache, k: jax.Array, v: jax.Array,
                  positions: jax.Array) -> PagedKVCache:
-    """Scatter S new K/V tokens per slot into the paged block pool (k/v
-    (B, S) + the pool's entry shape: (Hkv, Dh), or a latent width).
+    """Scatter S new K/V tokens per slot into the current layer's blocks of
+    the paged pool, in place (k/v (B, S) + the pool's entry shape: (Hkv,
+    Dh), or a latent width).
 
     ``positions`` (B, S) are the absolute token positions being written; each
-    maps to physical location ``(block_table[pos // bs], pos % bs)``.
-    Positions past a slot's reserved blocks land in the trash block (table
-    rows are trash-padded; positions past the table itself are redirected to
-    trash explicitly — clamping them into the last column could corrupt a
-    full row's final real block) or in the row's own reserved tail, which is
+    maps to global block ``global_table[pos // bs]`` at offset ``pos % bs``
+    of the pool with its layer and block axes merged.  Positions past a
+    slot's reserved blocks land in the layer's trash block (table rows are
+    trash-padded; positions past the table itself are redirected to it
+    explicitly — clamping them into the last column could corrupt a full
+    row's final real block) or in the row's own reserved tail, which is
     rewritten before any read (serve/kv_cache.py invariants) — so the write
     needs no predication.
     """
-    from repro.serve.kv_cache import TRASH_BLOCK
-
     B, S = positions.shape
     bs = cache.block_size
-    max_blocks = cache.block_table.shape[1]
+    table = cache.global_table()
+    max_blocks = table.shape[1]
     col = positions // bs
-    blk = jnp.take_along_axis(cache.block_table,
-                              jnp.clip(col, 0, max_blocks - 1), axis=1)
-    blk = jnp.where(col < max_blocks, blk, TRASH_BLOCK)         # (B, S)
-    off = positions % bs
-    kf = k.astype(cache.k.dtype).reshape((B * S,) + cache.k.shape[2:])
-    vf = v.astype(cache.v.dtype).reshape((B * S,) + cache.v.shape[2:])
-    kp = cache.k.at[blk.reshape(-1), off.reshape(-1)].set(kf)
-    vp = cache.v.at[blk.reshape(-1), off.reshape(-1)].set(vf)
-    return PagedKVCache(kp, vp, cache.block_table, cache.length + S)
+    blk = jnp.take_along_axis(table, jnp.clip(col, 0, max_blocks - 1), axis=1)
+    blk = jnp.where(col < max_blocks, blk, cache.base)          # (B, S)
+    idx = (blk.reshape(-1), (positions % bs).reshape(-1))
+    pk, pv = cache.flat()
+    kp = pk.at[idx].set(k.astype(pk.dtype).reshape((B * S,) + pk.shape[2:]))
+    vp = pv.at[idx].set(v.astype(pv.dtype).reshape((B * S,) + pv.shape[2:]))
+    return cache._replace(k=kp.reshape(cache.k.shape),
+                          v=vp.reshape(cache.v.shape),
+                          length=cache.length + S)
 
 
 def _paged_decode_attention(q: jax.Array, cache: PagedKVCache,
@@ -408,22 +409,26 @@ def _paged_decode_attention(q: jax.Array, cache: PagedKVCache,
                             ) -> jax.Array:
     """One-token attention against the paged pool, via the dispatch layer.
 
-    Pallas backends run the paged flash kernel: K/V blocks are DMA'd
-    straight through the scalar-prefetched block table with per-slot length
-    masking — the contiguous ``pool[table]`` gather never materializes.
+    The kernels get the whole pool by global block id and the layer's
+    table in those ids, so no layer's pool is sliced out.  Pallas backends
+    run the paged flash kernel: K/V blocks are DMA'd straight through the
+    scalar-prefetched block table with per-slot length masking — the
+    contiguous ``pool[table]`` gather never materializes.
     Other backends gather the table's columns — bounded, because the
     scheduler slices each bucket's table to its used-block count
     (serve/scheduler.py) instead of all ``max_blocks`` trash-padded columns
     — and run the policy-obeying masked einsums."""
+    pk, pv = cache.flat()
+    table = cache.global_table()
     lanes = lanes_lib.current_lanes()
     if lanes is not None:
         env_qk, ln_qk, lo_qk = lanes.for_class("attn_qk")
         env_pv, ln_pv, lo_pv = lanes.for_class("attn_pv")
         return dispatch_lib.dispatch_mixed_paged_attention(
-            q, cache.k, cache.v, cache.block_table, cache.length,
+            q, pk, pv, table, cache.length,
             env_qk, env_pv, ln_qk, lo_qk, ln_pv, lo_pv)
     return dispatch_lib.dispatch_paged_attention(
-        q, cache.k, cache.v, cache.block_table, cache.length,
+        q, pk, pv, table, cache.length,
         policy.mode("attn_qk"), policy.mode("attn_pv"))
 
 

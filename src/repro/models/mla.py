@@ -212,9 +212,9 @@ def mla_forward(
         if S == 1:
             def attend(q_lat, q_r):
                 return dispatch_lib.dispatch_latent_paged_attention(
-                    q_lat, q_r, new_cache.k, new_cache.v,
-                    new_cache.block_table, new_cache.length, mode_qk,
-                    mode_pv, scale=dims.softmax_scale)
+                    q_lat, q_r, *new_cache.flat(), new_cache.global_table(),
+                    new_cache.length, mode_qk, mode_pv,
+                    scale=dims.softmax_scale)
 
             return (_absorbed_decode(params, q_nope, q_rope, attend, dims,
                                      policy), new_cache)

@@ -35,6 +35,7 @@ from repro.models.layers import (dense_init, embed, embed_init, rms_norm,
                                  swiglu_mlp, unembed)
 from repro.models.mla import MLACache
 from repro.models.ssm import SSMCache
+from repro.serve.kv_cache import PagedKVCache
 
 
 # =========================================================================
@@ -292,19 +293,20 @@ def _scan_layers(layer_fn, h, layer_params, stacked_cache, cfg):
     in place, where a scan would copy every layer's slice out of the stack
     on each call.  The serving engine's prelimbed decode params are such a
     sequence (serve/engine.prelimb_dense_params); training, prefill and
-    AUTO serving pass stacked params and scan.  The cache stays stacked
-    either way.  Unrolled, the layers' new caches are stacked into a fresh
-    output, as the scan's outputs are: a write-back with ``.at[i].set``
-    would first copy the whole (undonated) input cache into the output.
+    AUTO serving pass stacked params and scan.  A stacked cache is sliced
+    per layer and the layers' new caches stacked, as the scan's outputs
+    are; a paged pool is threaded through the layers instead
+    (:func:`_thread_pool`).
 
     ``cfg.scan_group > 1`` nests the scan: the outer scan (rematerialized)
     saves one residual carry per *group* of layers instead of per layer —
     activation memory L/g × h instead of L × h at the cost of one in-group
     forward recompute during backward (same recompute as plain per-layer
     remat).  Mandatory for the 88-layer × 12288-wide cells."""
+    if isinstance(stacked_cache, PagedKVCache):
+        return _thread_pool(layer_fn, h, layer_params, stacked_cache, cfg)
     per_layer = isinstance(layer_params, (list, tuple))
-    aux0 = {"moe_aux": jnp.zeros((), jnp.float32),
-            "moe_zloss": jnp.zeros((), jnp.float32)}
+    aux0 = _aux_zeros()
     if cfg.scan_layers and not per_layer:
         n = jax.tree_util.tree_leaves(layer_params)[0].shape[0]
 
@@ -356,6 +358,45 @@ def _scan_layers(layer_fn, h, layer_params, stacked_cache, cfg):
     return h, aux_tot, new_caches
 
 
+def _thread_pool(layer_fn, h, layer_params, pool: PagedKVCache, cfg):
+    """Run the layer stack over the paged pool as a carry: model layer
+    ``i`` reads and writes pool layer ``pool.layer + i`` in place, and its
+    new pool is the next layer's input — nothing slices a layer out of the
+    pool or stacks the layers' pools back.  Returns the pool positioned at
+    the layer after the stack, so a second stack (an MoE model's layers
+    after its leading dense ones) continues where this one ends."""
+    first = pool.layer
+    per_layer = isinstance(layer_params, (list, tuple))
+    n = (len(layer_params) if per_layer
+         else jax.tree_util.tree_leaves(layer_params)[0].shape[0])
+
+    def run(lp, h, k, v, layer, aux_acc):
+        h, new, aux = layer_fn(lp, h, pool._replace(k=k, v=v, layer=layer))
+        return h, new.k, new.v, jax.tree_util.tree_map(jnp.add, aux_acc, aux)
+
+    k, v, aux = pool.k, pool.v, _aux_zeros()
+    if cfg.scan_layers and not per_layer:
+        def body(carry, xs):
+            lp, i = xs
+            h, k, v, aux_acc = carry
+            return run(lp, h, k, v, first + i, aux_acc), None
+
+        (h, k, v, aux), _ = jax.lax.scan(
+            _maybe_remat(body, cfg), (h, k, v, aux),
+            (layer_params, jnp.arange(n, dtype=jnp.int32)))
+    else:
+        for i in range(n):
+            lp = (layer_params[i] if per_layer
+                  else jax.tree_util.tree_map(lambda x: x[i], layer_params))
+            h, k, v, aux = run(lp, h, k, v, first + i, aux)
+    return h, aux, pool._replace(k=k, v=v, layer=first + n)
+
+
+def _aux_zeros():
+    return {"moe_aux": jnp.zeros((), jnp.float32),
+            "moe_zloss": jnp.zeros((), jnp.float32)}
+
+
 _NO_AUX = {"moe_aux": jnp.zeros(()), "moe_zloss": jnp.zeros(())}
 
 
@@ -404,6 +445,11 @@ def forward(
             new_cache = ModelCache(attn=nc)
 
     elif cfg.family == "moe":
+        attn_c = cache.attn if cache is not None else None
+        dense_c = cache.dense_attn if cache is not None else None
+        # a paged pool holds every layer: the leading dense layers run its
+        # first layers, and the MoE layers go on with the pool they return
+        pooled = isinstance(attn_c, PagedKVCache)
         nc_dense = None
         if cfg.first_k_dense:
             def dense_fn(lp, h, lc):
@@ -413,16 +459,16 @@ def forward(
 
             h, _, nc_dense = _scan_layers(
                 dense_fn, h, params["dense_layers"],
-                cache.dense_attn if cache is not None else None, cfg)
+                attn_c if pooled else dense_c, cfg)
+            if pooled:
+                attn_c, nc_dense = nc_dense, None
 
         def moe_fn(lp, h, lc):
             h, nc, aux = _moe_layer_fwd(lp, h, cfg, policy, positions, lc,
                                         mesh)
             return h, nc, aux
 
-        h, aux, nc = _scan_layers(moe_fn, h, params["layers"],
-                                  cache.attn if cache is not None else None,
-                                  cfg)
+        h, aux, nc = _scan_layers(moe_fn, h, params["layers"], attn_c, cfg)
         if cache is not None:
             new_cache = ModelCache(attn=nc, dense_attn=nc_dense)
 
@@ -503,14 +549,10 @@ def _hybrid_forward(params, h, cfg, policy, positions, cache):
 
 
 def _cache_length(cache: ModelCache, cfg: ModelConfig):
-    from repro.serve.kv_cache import PagedKVCache
-
     for c in (cache.attn, cache.ssm, cache.shared_attn):
         if c is not None:
             ln = c.length
             if isinstance(c, PagedKVCache):
-                # stacked (L, B) per-slot lengths -> (B,): every layer
-                # carries the same host state, keep the per-slot vector
-                return ln[0] if ln.ndim > 1 else ln
+                return ln  # (B,) per-slot lengths
             return ln[tuple(0 for _ in range(ln.ndim))] if ln.ndim else ln
     raise ValueError("empty cache")

@@ -46,7 +46,7 @@ from repro.core.limbs import PrelimbedWeight, prelimb_weight
 from repro.core.policy import PrecisionPolicy
 from repro.models import transformer as T
 from repro.models.mla import head_matrices
-from repro.serve.kv_cache import PagedKVCache
+from repro.serve.kv_cache import PagedKVCache, PagedKVPool
 from repro.train.trainer import make_prefill_step, make_serve_step
 
 # op classes whose weights sit on the decode path (the pre-limb set); a
@@ -197,30 +197,11 @@ class Request:
     done: bool = False
 
 
-def _paged_cache(cfg: ModelConfig, pool_k, pool_v, table, lengths
-                 ) -> T.ModelCache:
-    """The model's cache over the paged pool: every layer's view of the
-    pool with the slots' block table and lengths.  An MoE stack's leading
-    dense layers hold the pool's first layers (``dense_attn``)."""
-    def view(k, v):
-        n = k.shape[0]
-        return PagedKVCache(
-            k=k, v=v, block_table=jnp.broadcast_to(table, (n,) + table.shape),
-            length=jnp.broadcast_to(lengths, (n,) + lengths.shape))
-
-    if cfg.family == "moe" and cfg.first_k_dense:
-        f = cfg.first_k_dense
-        return T.ModelCache(attn=view(pool_k[f:], pool_v[f:]),
-                            dense_attn=view(pool_k[:f], pool_v[:f]))
-    return T.ModelCache(attn=view(pool_k, pool_v))
-
-
-def _new_pools(cache: T.ModelCache):
-    """The step's new (k, v) pools, layers in the pool's order."""
-    if cache.dense_attn is None:
-        return cache.attn.k, cache.attn.v
-    return (jnp.concatenate([cache.dense_attn.k, cache.attn.k]),
-            jnp.concatenate([cache.dense_attn.v, cache.attn.v]))
+def _paged_cache(pool_k, pool_v, table, lengths) -> T.ModelCache:
+    """The model's cache over the paged pool: the whole pool with the slots'
+    block table and lengths, which the layers thread from the first to the
+    last (``transformer._thread_pool``)."""
+    return T.ModelCache(attn=PagedKVCache(pool_k, pool_v, table, lengths))
 
 
 def make_paged_prefill_step(cfg: ModelConfig, policy: PrecisionPolicy,
@@ -238,15 +219,17 @@ def make_paged_prefill_step(cfg: ModelConfig, policy: PrecisionPolicy,
     is the per-slot max |logit| scalar the numerical guardrail polices
     (``jnp.max`` propagates NaN, so non-finite logits surface as a
     non-finite stat); computing it inside the step keeps the check free of
-    extra launches.
+    extra launches.  The pools are the ones handed in, updated in place:
+    the engine jits every paged step with them donated
+    (``POOL_ARGNUMS``).
     """
     def step(params, pool_k, pool_v, table, lengths, tokens, last_idx):
-        cache = _paged_cache(cfg, pool_k, pool_v, table, lengths)
+        cache = _paged_cache(pool_k, pool_v, table, lengths)
         logits, _, new_cache = T.forward(params, {"tokens": tokens}, cfg,
                                          policy, cache=cache, mesh=mesh)
         last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1, axis=1)
         stat = jnp.max(jnp.abs(last[:, 0, :]), axis=-1)
-        return (last, stat) + _new_pools(new_cache)
+        return last, stat, new_cache.attn.k, new_cache.attn.v
 
     return step
 
@@ -264,11 +247,11 @@ def make_paged_decode_step(cfg: ModelConfig, policy: PrecisionPolicy,
     scalar per slot and no extra launch.
     """
     def step(params, pool_k, pool_v, table, lengths, tokens):
-        cache = _paged_cache(cfg, pool_k, pool_v, table, lengths)
+        cache = _paged_cache(pool_k, pool_v, table, lengths)
         logits, _, new_cache = T.forward(params, {"tokens": tokens}, cfg,
                                          policy, cache=cache, mesh=mesh)
         stat = jnp.max(jnp.abs(logits[:, -1, :]), axis=-1)
-        return (logits, stat) + _new_pools(new_cache)
+        return logits, stat, new_cache.attn.k, new_cache.attn.v
 
     return step
 
@@ -297,14 +280,14 @@ def make_mixed_decode_step(cfg: ModelConfig, envelope: LaneEnvelope,
 
     def step(params, pool_k, pool_v, table, lengths, tokens, lane_n,
              lane_ord):
-        cache = _paged_cache(cfg, pool_k, pool_v, table, lengths)
+        cache = _paged_cache(pool_k, pool_v, table, lengths)
         ctx = LaneCtx(envelope, lane_n.astype(jnp.int32),
                       lane_ord.astype(jnp.int32))
         with lane_scope(ctx):
             logits, _, new_cache = T.forward(params, {"tokens": tokens}, cfg,
                                              carrier, cache=cache, mesh=mesh)
         stat = jnp.max(jnp.abs(logits[:, -1, :]), axis=-1)
-        return (logits, stat) + _new_pools(new_cache)
+        return logits, stat, new_cache.attn.k, new_cache.attn.v
 
     return step
 
@@ -328,6 +311,12 @@ def pool_entry_shapes(cfg: ModelConfig):
         return (cfg.mla.kv_lora,), (cfg.mla.qk_rope_dim,)
     kv = (cfg.n_kv_heads, cfg.resolved_head_dim)
     return kv, kv
+
+
+# the arguments of every paged step that hold the pool (``pool_k``,
+# ``pool_v``): the engine jits the steps with them donated, so each step
+# updates the pool in place
+POOL_ARGNUMS = (1, 2)
 
 
 class ServeEngine:
@@ -364,6 +353,7 @@ class ServeEngine:
         self.step_cache_misses = 0
         self.prelimb_cache_hits = 0
         self.prelimb_cache_misses = 0
+        self.pool_steps_donated = 0
         # (n_limbs, id(params)) -> prelimbed tree: the id guards against a
         # live params swap (eng.params = reloaded) silently leaving decode on
         # stale limb stacks while prefill uses the new weights
@@ -402,7 +392,8 @@ class ServeEngine:
         counted.__name__ = counted.__qualname__ = name
         return counted
 
-    def _cached_steps(self, cache: Dict, key, factories: Tuple) -> Tuple:
+    def _cached_steps(self, cache: Dict, key, factories: Tuple,
+                      donate_argnums: Tuple = ()) -> Tuple:
         """Shared LRU discipline for every jit'd step cache (keyed by policy
         or lane envelope): touch on hit, evict oldest past MAX_POLICY_CACHE,
         trace (with the engine's backend pinned) on miss.  Each step is
@@ -421,7 +412,8 @@ class ServeEngine:
                 jax.jit(self._counted_trace(
                     pin_backend(make(self.cfg, key, self.mesh),
                                 self.matmul_backend),
-                    make.__name__.removeprefix("make_").removesuffix("_step")))
+                    make.__name__.removeprefix("make_").removesuffix("_step")),
+                    donate_argnums=donate_argnums)
                 for make in factories)
         return cache[key]
 
@@ -444,7 +436,8 @@ class ServeEngine:
             raise NotImplementedError(err)
         return self._cached_steps(
             self._paged_step_cache, policy,
-            (make_paged_prefill_step, make_paged_decode_step))
+            (make_paged_prefill_step, make_paged_decode_step),
+            donate_argnums=POOL_ARGNUMS)
 
     def mixed_decode_step_for(self, envelope: LaneEnvelope):
         """jit'd partitioned-lane decode step for one static lane envelope.
@@ -464,7 +457,18 @@ class ServeEngine:
                 f"(family={self.cfg.family!r}, "
                 f"mla={self.cfg.mla is not None})")
         return self._cached_steps(self._mixed_step_cache, envelope,
-                                  (make_mixed_decode_step,))[0]
+                                  (make_mixed_decode_step,),
+                                  donate_argnums=POOL_ARGNUMS)[0]
+
+    def launch_paged(self, step, pool: PagedKVPool, params, *args) -> list:
+        """Launch one paged step (of :meth:`paged_steps_for` or
+        :meth:`mixed_decode_step_for`) over ``pool``, which it takes
+        donated, and adopt the pools it returns at once; returns the step's
+        other outputs (logits, guard stat)."""
+        *out, k, v = step(params, pool.k, pool.v, *args)
+        pool.update(k, v)
+        self.pool_steps_donated += 1
+        return out
 
     def set_policy(self, policy: Union[PrecisionPolicy, str, bytes, dict]
                    ) -> PrecisionPolicy:
@@ -524,7 +528,8 @@ class ServeEngine:
         spend on zero padding to the kernel's blocks;
         ``expert_limb_bytes`` the cached limb stacks of the held experts,
         and ``experts_held`` how many experts each MoE layer holds (0 for
-        a model without routed experts)."""
+        a model without routed experts); ``pool_steps_donated`` counts the
+        paged steps launched with the pool donated (:meth:`launch_paged`)."""
         moe = self.cfg.moe
         return {
             "trace_events": self.trace_events,
@@ -537,6 +542,7 @@ class ServeEngine:
             "expert_limb_bytes": sum(expert_limb_bytes(t) for t
                                      in self._prelimb_cache.values()),
             "experts_held": moe.held if moe is not None else 0,
+            "pool_steps_donated": self.pool_steps_donated,
         }
 
     # -- single-request path (prefill writes the whole pool cache; simple and

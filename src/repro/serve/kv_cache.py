@@ -31,15 +31,17 @@ blocks.  Two invariants make the scheme safe without any in-kernel masking:
     step that produced it before any read — so prefill padding garbage in a
     request's own reserved tail is always overwritten before it is visible.
 
-:class:`PagedKVCache` is the *jit-side* view (a pytree: pool arrays + block
-table + per-slot lengths) threaded through the model's layer scan exactly
-like the dense :class:`~repro.models.attention.KVCache`.  This module has no
-model dependencies so ``models/attention.py`` can import it freely.
+:class:`PagedKVCache` is the *jit-side* view (a pytree: the pool arrays,
+block table, per-slot lengths and the layer being run) that the model's
+layers thread from one to the next; each layer writes its new rows into the
+pool in place and its kernel reads the pool where it lies, through global
+block ids (layer ``l``'s block ``b`` is ``l * n_blocks + b``).  This module
+has no model dependencies so ``models/attention.py`` can import it freely.
 """
 from __future__ import annotations
 
 import threading
-from typing import Iterable, List, NamedTuple, Optional, Sequence
+from typing import Iterable, List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -52,22 +54,41 @@ TRASH_BLOCK = 0
 
 
 class PagedKVCache(NamedTuple):
-    """Jit-side paged cache view (per layer after the scan slices it).
+    """Jit-side view of the whole paged pool at one layer.
 
-    Stacked form carries a leading ``n_layers`` dim on every field
-    (``block_table``/``length`` are per-layer copies of the same host state
-    so they ride the layer scan like any stacked cache leaf).
+    ``k``/``v`` hold every layer; ``layer`` is the one the current model
+    layer reads and writes.  It addresses the pool through global block
+    ids: :meth:`flat` merges the layer and block axes (two major axes, so a
+    bitcast, not a copy) and :meth:`global_table` adds the layer's
+    ``base`` (``layer * n_blocks``, also its trash block) to the host's
+    block ids.  The view copies no pool bytes, so a step that takes the
+    pool donated updates it in place.
     """
 
-    k: jax.Array            # (n_blocks, block_size) + k entry shape
-    v: jax.Array            # (n_blocks, block_size) + v entry shape
-    block_table: jax.Array  # (B, max_blocks) int32 physical block ids
+    k: jax.Array            # (n_layers, n_blocks, block_size) + k entry shape
+    v: jax.Array            # (n_layers, n_blocks, block_size) + v entry shape
+    block_table: jax.Array  # (B, max_blocks) int32 block ids within a layer
     length: jax.Array       # (B,) int32 valid prefix length per slot
+    layer: Union[int, jax.Array] = 0  # pool layer of the current model layer
 
     @property
     def block_size(self) -> int:
-        """Positions per block (of the per-layer view)."""
-        return self.k.shape[1]
+        """Positions per block."""
+        return self.k.shape[2]
+
+    @property
+    def base(self) -> Union[int, jax.Array]:
+        """Global id of the layer's first block: its trash block."""
+        return self.layer * self.k.shape[1] + TRASH_BLOCK
+
+    def global_table(self) -> jax.Array:
+        """The block table in global ids of the current layer."""
+        return self.block_table + self.base
+
+    def flat(self) -> Tuple[jax.Array, jax.Array]:
+        """``(k, v)`` indexed by global block id: (n_layers * n_blocks,
+        block_size) + entry shape."""
+        return tuple(x.reshape((-1,) + x.shape[2:]) for x in (self.k, self.v))
 
 
 class BlockPoolExhausted(RuntimeError):
@@ -77,18 +98,18 @@ class BlockPoolExhausted(RuntimeError):
 class PagedKVPool:
     """Device block pool + host free-list allocator.
 
-    The device arrays are functional (each jit step returns updated pools via
-    :meth:`update`); the free list is host state guarded by a lock, so fleet
-    engines sharing one pool (a disaggregated prefill engine allocating
-    while its decode engine frees evicted blocks) never race the accounting.
-    The device arrays themselves have a single-writer discipline: exactly
-    one engine step may be in flight per pool at a time (each step is a
-    functional read-modify-write of the whole pool array, so two concurrent
-    steps from the same base would lose each other's writes — the fleet
-    serializes steps per pool; cross-pool handoff copies blocks instead).
-    Allocation never hands out a block twice: a block is either in
-    ``_free``, in ``_live`` (owned by exactly one request), or the trash
-    block.
+    Each jit step takes the pool arrays donated, writes its new rows into
+    them in place and returns them; the arrays handed in are deleted, so
+    the caller adopts the returned ones at once (:meth:`update`) and nothing
+    may keep a reference to a pool array across a step.  The free list is
+    host state guarded by a lock, so fleet engines sharing one pool (a
+    disaggregated prefill engine allocating while its decode engine frees
+    evicted blocks) never race the accounting.  The device arrays have a
+    single-writer discipline: exactly one engine step may be in flight per
+    pool at a time (the fleet serializes steps per pool; cross-pool handoff
+    copies blocks instead).  Allocation never hands out a block twice: a
+    block is either in ``_free``, in ``_live`` (owned by exactly one
+    request), or the trash block.
     """
 
     def __init__(self, n_layers: int, n_blocks: int, block_size: int,
@@ -221,7 +242,8 @@ class PagedKVPool:
 
     # ---- jit-side pool hand-back ------------------------------------------
     def update(self, k: jax.Array, v: jax.Array) -> None:
-        """Adopt the pool arrays a jit step returned (functional update)."""
+        """Adopt the pool arrays a jit step returned in place of the ones it
+        took donated."""
         if k.shape != self.k.shape or v.shape != self.v.shape:
             raise ValueError(
                 f"pool shape changed: {k.shape} vs {self.k.shape}")

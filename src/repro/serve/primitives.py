@@ -355,11 +355,9 @@ def prefill_request(engine, pool: PagedKVPool, req: ScheduledRequest) -> int:
         table = pool.table_row(req.blocks)[None, :table_width(pool, [req])]
         lengths = np.zeros((1,), np.int32)
         with TraceAnnotation("serve.prefill.launch"):
-            logits, _stat, new_k, new_v = prefill_fn(
-                engine.params, pool.k, pool.v,
-                jnp.asarray(table), jnp.asarray(lengths),
-                jnp.asarray(tokens), np.int32(n - 1))
-        pool.update(new_k, new_v)
+            logits, _stat = engine.launch_paged(
+                prefill_fn, pool, engine.params, jnp.asarray(table),
+                jnp.asarray(lengths), jnp.asarray(tokens), np.int32(n - 1))
         req.length = n
         now = time.perf_counter()
         if req.t_first < 0:
@@ -448,10 +446,9 @@ def decode_bucket_step(engine, pool: PagedKVPool,
             _, decode_fn = engine.paged_steps_for(policy)
             params = engine._decode_params_for(policy)
         with TraceAnnotation("serve.decode.launch"):
-            logits, stat, new_k, new_v = decode_fn(
-                params, pool.k, pool.v, jnp.asarray(table),
+            logits, stat = engine.launch_paged(
+                decode_fn, pool, params, jnp.asarray(table),
                 jnp.asarray(lengths), jnp.asarray(tokens))
-        pool.update(new_k, new_v)
         with TraceAnnotation("serve.decode.sync"):
             toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
             stat_np = np.asarray(stat)[: len(reqs)]
@@ -544,11 +541,10 @@ def decode_mixed_step(engine, pool: PagedKVPool,
             decode_fn = engine.mixed_decode_step_for(env)
             params = engine._decode_params_for_limbs(env.max_limbs)
         with TraceAnnotation("serve.decode.launch"):
-            logits, stat, new_k, new_v = decode_fn(
-                params, pool.k, pool.v, jnp.asarray(table),
+            logits, stat = engine.launch_paged(
+                decode_fn, pool, params, jnp.asarray(table),
                 jnp.asarray(lengths), jnp.asarray(tokens),
                 jnp.asarray(lane_n), jnp.asarray(lane_ord))
-        pool.update(new_k, new_v)
         with TraceAnnotation("serve.decode.sync"):
             toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
             stat_np = np.asarray(stat)[: len(reqs)]

@@ -85,8 +85,8 @@ def test_latent_norm_and_rope_key_written_to_the_pool():
     x = jnp.asarray(rng.standard_normal((1, 5, dims.d_model)), jnp.float32)
     bs = 4
     cache = PagedKVCache(
-        k=jnp.zeros((4, bs, dims.kv_lora)),
-        v=jnp.zeros((4, bs, dims.qk_rope_dim)),
+        k=jnp.zeros((1, 4, bs, dims.kv_lora)),
+        v=jnp.zeros((1, 4, bs, dims.qk_rope_dim)),
         block_table=jnp.asarray([[1, 2]], jnp.int32),
         length=jnp.zeros((1,), jnp.int32))
     _, new = jax.jit(lambda p, x, c: mla_forward(
@@ -95,10 +95,11 @@ def test_latent_norm_and_rope_key_written_to_the_pool():
     c = x64 @ np.asarray(p["w_dkv"], np.float64)
     c = c / np.sqrt(np.mean(c * c, -1, keepdims=True) + dims.norm_eps)
     c = c * np.asarray(p["kv_norm"], np.float64)
-    got = np.concatenate([np.asarray(new.k[1]), np.asarray(new.k[2])])[:5]
+    got = np.concatenate([np.asarray(new.k[0, 1]),
+                          np.asarray(new.k[0, 2])])[:5]
     np.testing.assert_allclose(got, c, rtol=2e-5, atol=2e-6)
     kr0 = x64[0] @ np.asarray(p["w_kr"], np.float64)
-    np.testing.assert_allclose(np.asarray(new.v[1, 0]), kr0, rtol=2e-5,
+    np.testing.assert_allclose(np.asarray(new.v[0, 1, 0]), kr0, rtol=2e-5,
                                atol=2e-6)
     assert np.asarray(new.length).tolist() == [5]
 
@@ -199,9 +200,11 @@ def _full_logits(params, tokens):
     return T.forward(params, {"tokens": tokens[None]}, DS, M23)[0][0]
 
 
-def _paged_logits(eng, params, policy):
+def _paged_logits(eng, params, policy, handed=None):
     """Paged prefill of a 7-token prompt, then 6 decode steps on greedy
-    tokens, through the engine's jit'd paged steps: each step's logits."""
+    tokens, through the engine's jit'd paged steps: each step's logits,
+    the sequence and the last pools (``handed`` collects the pools each
+    step was handed)."""
     prefill, decode = eng.paged_steps_for(policy)
     pool = PagedKVPool(DS.n_layers, 9, 4, *pool_entry_shapes(DS),
                        max_blocks_per_seq=4)
@@ -209,19 +212,22 @@ def _paged_logits(eng, params, policy):
     table = jnp.asarray(pool.table_row([1, 2, 3, 4])[None])
     toks = np.zeros((1, 8), np.int32)
     toks[0, :7] = prompt
-    last, _, k, v = prefill(params, pool.k, pool.v, table,
-                            jnp.zeros((1,), jnp.int32), jnp.asarray(toks),
-                            np.int32(6))
+    k, v = pool.k, pool.v
+    handed = [] if handed is None else handed
+    handed.append((k, v))
+    last, _, k, v = prefill(params, k, v, table, jnp.zeros((1,), jnp.int32),
+                            jnp.asarray(toks), np.int32(6))
     got, seq = [np.asarray(last[0, 0])], [int(t) for t in prompt]
     dp = eng._decode_params_for(policy)
     for _ in range(6):
         tok = int(np.argmax(got[-1]))
         seq.append(tok)
+        handed.append((k, v))
         logits, _, k, v = decode(dp, k, v, table,
                                  jnp.asarray([len(seq) - 1], jnp.int32),
                                  jnp.asarray([[tok]], jnp.int32))
         got.append(np.asarray(logits[0, 0]))
-    return np.stack(got), seq
+    return np.stack(got), seq, (k, v)
 
 
 def test_paged_prefill_and_decode_match_the_full_forward(ds_engine,
@@ -234,11 +240,35 @@ def test_paged_prefill_and_decode_match_the_full_forward(ds_engine,
     measured difference is 4.4e-7.  Serving at M16 (bound 1.2e-4) reads
     2.9e-5, outside it."""
     for policy, inside in ((M23, True), (M16, False)):
-        got, seq = _paged_logits(ds_engine, ds_params, policy)
+        got, seq, _ = _paged_logits(ds_engine, ds_params, policy)
         want = np.asarray(_full_logits(ds_params, jnp.asarray(seq)))
         want = want[6:6 + len(got)]
         err = np.abs(got - want).max() / np.abs(want).max()
         assert (err <= 4e-6) == inside, (policy, err)
+
+
+def test_donated_steps_match_slice_and_stack(ds_params, slice_and_stack):
+    """The paged prefill and absorbed decode steps take the latent pool
+    donated (every pool they were handed is deleted) and update it in
+    place, one pool threaded through the dense layer (pool layer 0) and
+    the MoE layer (pool layer 1): logits and the last pool bit for bit as
+    the per-layer slice-and-stack path computes them."""
+    def run():
+        eng = ServeEngine(DS, ds_params, max_batch=2, max_seq=32,
+                          policy=M23)
+        return _paged_logits(eng, ds_params, M23, handed)
+
+    handed = []
+    got, seq, (k, v) = run()
+    assert len(handed) == 7
+    assert all(a.is_deleted() and b.is_deleted() for a, b in handed)
+    with slice_and_stack():
+        want, want_seq, (ref_k, ref_v) = run()
+    assert seq == want_seq
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(np.asarray(k), np.asarray(ref_k))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(ref_v))
+    assert np.asarray(k)[:, 1:5].any(axis=(1, 2, 3)).all()  # both layers
 
 
 def test_scheduler_serves_the_full_forwards_tokens_and_counts(ds_engine,

@@ -15,8 +15,10 @@ from repro.models import transformer as T
 from repro.serve.engine import ServeEngine
 from repro.serve.fleet import (
     DOWNGRADE_CHAIN,
+    DecodeEngine,
     FleetRouter,
     KVHandoff,
+    PrefillEngine,
     deliver,
     make_fleet,
 )
@@ -122,6 +124,33 @@ class TestCrossPoolHandoff:
         src.transfer_blocks(dst, sb, db)
         assert jnp.array_equal(dst.k[:, db], src.k[:, sb])
         assert jnp.array_equal(dst.v[:, db], src.v[:, sb])
+
+    def test_handoff_after_donated_prefill_decodes_in_the_new_pool(
+            self, params):
+        """A request prefilled into one pool by a donated step hands its
+        blocks to a foreign pool and decodes there, donated again: the
+        blocks arrive bit for bit, the pool the prefill was handed is
+        deleted, and the tokens are the single scheduler's."""
+        eng = _engine(params)
+        prompt = np.arange(3, 9, dtype=np.int32)
+        want = ContinuousScheduler(eng, n_blocks=8, block_size=4).run(
+            [ScheduledRequest(rid=0, prompt=prompt, max_new=4)])[0].out
+        src, dst = (PagedKVPool(CFG.n_layers, 8, 4, KV, KV,
+                                max_blocks_per_seq=4) for _ in range(2))
+        dst.alloc(3)    # the request's blocks land at other ids there
+        prefill, decode = PrefillEngine(eng, src), DecodeEngine(eng, dst)
+        req = ScheduledRequest(rid=0, prompt=prompt, max_new=4)
+        assert prefill.try_admit(req)
+        handed = src.k
+        (handoff,), _ = prefill.step()
+        assert handed.is_deleted()
+        sb, sk, sv = list(req.blocks), src.k[:, req.blocks], src.v[:, req.blocks]
+        assert decode.accept(handoff) and req.blocks != sb
+        assert jnp.array_equal(dst.k[:, req.blocks], sk)
+        assert jnp.array_equal(dst.v[:, req.blocks], sv)
+        while decode.n_active:
+            decode.step()
+        assert req.out == want
 
     def test_transfer_rejects_geometry_mismatch(self):
         src = self._pool()

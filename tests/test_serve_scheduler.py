@@ -327,6 +327,126 @@ class TestSchedulerInvariants:
 
 
 # =========================================================================
+# the pool through a step: donated, written in place, read by global id
+# =========================================================================
+# one of each family the paged path serves: dense GQA, and an MoE stack with
+# latent attention whose leading dense layer runs the pool's first layer
+FAMILIES = {"dense": CFG, "moe": get_config("deepseek-v2-lite-16b",
+                                            smoke=True)}
+
+
+def _spy_paged_steps(eng, seen):
+    """Record (pool_k in, pool_v in, pool_k out, pool_v out) of every paged
+    step the engine launches."""
+    def spy(fn):
+        def call(params, pk, pv, *rest):
+            out = fn(params, pk, pv, *rest)
+            seen.append((pk, pv) + tuple(out[-2:]))
+            return out
+        return call
+
+    paged, mixed = eng.paged_steps_for, eng.mixed_decode_step_for
+    eng.paged_steps_for = lambda policy: tuple(map(spy, paged(policy)))
+    eng.mixed_decode_step_for = lambda env: spy(mixed(env))
+
+
+class TestDonatedPool:
+    @pytest.fixture(scope="class")
+    def family_params(self):
+        return {name: T.init_params(cfg, jax.random.PRNGKey(1))
+                for name, cfg in FAMILIES.items()}
+
+    def _serve(self, family, params, modes, *, backend=None, seen=None):
+        """A join mid-stream and an eviction over a few ticks; returns the
+        tokens and the scheduler (``seen`` records the paged steps)."""
+        cfg = FAMILIES[family]
+        eng = ServeEngine(cfg, params[family], max_batch=4, max_seq=64,
+                          policy=PrecisionPolicy.serve_default(),
+                          matmul_backend=backend)
+        if seen is not None:
+            _spy_paged_steps(eng, seen)
+        sched = ContinuousScheduler(eng, n_blocks=24, block_size=4)
+        rng = np.random.default_rng(8)
+        reqs = [ScheduledRequest(rid=i, prompt=rng.integers(
+                    0, cfg.vocab, size=n).astype(np.int32), max_new=m,
+                    mode=mode, arrival=a)
+                for i, (n, m, a, mode) in enumerate(
+                    zip((5, 9, 3), (6, 3, 4), (0, 0, 2), modes))]
+        done = sched.run(reqs)
+        return {r.rid: r.out for r in done}, sched
+
+    @pytest.mark.parametrize("family,backend", [("dense", "pallas_interpret"),
+                                                ("moe", None)])
+    def test_ticks_match_slice_and_stack(self, family_params, family,
+                                         backend, slice_and_stack):
+        """The scheduler's tokens and the whole pool after the last tick,
+        bit for bit, against the per-layer slice-and-stack path."""
+        modes = [None] * 3
+        got, sched = self._serve(family, family_params, modes,
+                                 backend=backend)
+        with slice_and_stack():
+            want, ref = self._serve(family, family_params, modes,
+                                    backend=backend)
+        assert got == want
+        np.testing.assert_array_equal(np.asarray(sched.pool.k),
+                                      np.asarray(ref.pool.k))
+        np.testing.assert_array_equal(np.asarray(sched.pool.v),
+                                      np.asarray(ref.pool.v))
+
+    @pytest.mark.parametrize("family", sorted(FAMILIES))
+    def test_step_deletes_the_pool_it_was_handed(self, family_params,
+                                                 family):
+        """After each paged step the arrays handed in are deleted (donated)
+        and the pool holds the step's outputs."""
+        cfg = FAMILIES[family]
+        eng = ServeEngine(cfg, family_params[family], max_batch=2,
+                          max_seq=32, policy=PrecisionPolicy.serve_default())
+        seen = []
+        _spy_paged_steps(eng, seen)
+        sched = ContinuousScheduler(eng, n_blocks=8, block_size=4)
+        sched.submit(ScheduledRequest(
+            rid=0, prompt=np.arange(5, dtype=np.int32), max_new=3))
+        for n in (2, 3):     # prefill + decode, then a decode
+            sched.step()
+            assert len(seen) == n
+            pk, pv, k, v = seen[-1]
+            assert pk.is_deleted() and pv.is_deleted()
+            assert sched.pool.k is k and sched.pool.v is v
+
+    def test_pool_steps_donated_counts_each_launch(self, family_params):
+        """``pool_steps_donated`` counts every paged launch once — each
+        prefill and each decode launch — and the scheduler's stats()
+        report it."""
+        seen = []
+        _, sched = self._serve("dense", family_params, [None] * 3, seen=seen)
+        n = sched.engine.cache_stats()["pool_steps_donated"]
+        assert n == len(seen) == sched.prefills + sched.decode_launches
+        assert sched.prefills == 3 and sched.decode_launches > 0
+        assert sched.stats()["pool_steps_donated"] == n
+
+    def test_mixed_lane_decode_matches_slice_and_stack(self, family_params,
+                                                       slice_and_stack):
+        """A mixed-format batch (one partitioned-lane launch a tick) takes
+        the pool donated and matches the slice-and-stack path bit for
+        bit."""
+        modes = ["M8", "M23", "M16"]
+        seen = []
+        got, sched = self._serve("dense", family_params, modes, seen=seen)
+        assert sched.stats()["launches_per_tick"] == 1.0
+        assert sched.engine._mixed_step_cache
+        assert all(pk.is_deleted() and pv.is_deleted()
+                   for pk, pv, _, _ in seen)
+        assert sched.engine.pool_steps_donated == len(seen)
+        with slice_and_stack():
+            want, ref = self._serve("dense", family_params, modes)
+        assert got == want
+        np.testing.assert_array_equal(np.asarray(sched.pool.k),
+                                      np.asarray(ref.pool.k))
+        np.testing.assert_array_equal(np.asarray(sched.pool.v),
+                                      np.asarray(ref.pool.v))
+
+
+# =========================================================================
 # chunked_attention ragged fix (unit level)
 # =========================================================================
 class TestRaggedChunkedAttention:

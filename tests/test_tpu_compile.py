@@ -1,8 +1,8 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e at
 the paper model's widths (``paper-mpfp-100m``: d_model 768, 12 heads of 64,
 d_ff 3072, vocab 32000), on a described ``v5e:2x2`` topology — no chip; and
-of the paged decode step at the widths of the benchmark's models,
-ChatGLM3-6B and DeepSeek-V2-Lite.
+of the paged decode and prefill steps at the widths of the benchmark's
+models, ChatGLM3-6B and DeepSeek-V2-Lite.
 
 Interpret mode accepts kernels that Mosaic refuses (grid / dimension-
 semantics mismatches, unaligned tiles, VMEM overruns); these compiles catch
@@ -14,6 +14,7 @@ The topology is described inside a module fixture, never at import: only one
 process may load the TPU library at a time, so every test worker must
 collect the same tests and only the one given this file loads it.
 """
+import dataclasses
 import json
 import re
 from pathlib import Path
@@ -32,7 +33,9 @@ from repro.kernels import mp_attention, ops
 from repro.models import transformer as T
 from repro.models.mla import MLADims
 from repro.models.moe import MoEDims
-from repro.serve.engine import make_paged_decode_step, prelimb_dense_params
+from repro.serve.engine import (POOL_ARGNUMS, make_paged_decode_step,
+                                make_paged_prefill_step, pool_entry_shapes,
+                                prelimb_dense_params)
 
 CFG = get_config("paper-mpfp-100m")
 D, FF, V = CFG.d_model, CFG.d_ff, CFG.vocab
@@ -268,3 +271,132 @@ def test_moe_mla_paged_decode_reads_limb_stacks_in_place(one_chip,
     assert len(hits("moe_expert", calls)) == 24
     assert hits("mla_attention", latent) == latent
     assert not hits("mla_attention", calls)
+
+
+# the benchmark cells' pool: 2561 blocks, so it does not fit the chip's
+# on-core memory and stays where it lies
+POOL_BLOCKS_CELL = 2561
+# DeepSeek-V2-Lite with two MoE layers after its dense one: the prefill's
+# MoE stack then scans
+DSV2_3L = dataclasses.replace(DSV2, name="deepseek-v2-lite-3l", n_layers=3)
+POOL_OPS = {"parameter", "bitcast", "scatter", "fusion", "get-tuple-element",
+            "tuple", "while"}
+
+
+_INSTRUCTION = re.compile(r"^\s*(ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9-]*)\(")
+
+
+def _instructions(text):
+    """(computation, name, result type, opcode, is root) of every HLO
+    instruction."""
+    out, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m:
+            out.append((comp, m.group(2), m.group(3), m.group(4),
+                        bool(m.group(1))))
+    return out
+
+
+@pytest.mark.parametrize("cfg,phase", [(GLM, "decode"), (GLM, "prefill"),
+                                       (DSV2_3L, "decode"),
+                                       (DSV2_3L, "prefill")],
+                         ids=["glm-decode", "glm-prefill", "dsv2-decode",
+                              "dsv2-prefill"])
+def test_donated_paged_step_updates_the_pool_in_place(one_chip, monkeypatch,
+                                                      cfg, phase):
+    """The paged step jitted as the engine jits it (the pool donated,
+    ``POOL_ARGNUMS``): both pool parameters alias the step's pool outputs;
+    no op makes an f32 array shaped like the pool, a layer of it or one
+    layer's slice — only bitcasts, the in-place scatter of the new rows and
+    the layer scan's carry touch it, so no slice, pad, concatenate or
+    dynamic-update-slice moves pool bytes; and the attention kernel reads
+    the whole pool by global block id, the latent kernel's operands still
+    the benchmark's ``mla_attention`` family.
+
+    One exception, DeepSeek's rope-key pool: the chip's default layout of
+    ``f32[L,N,64,64]`` puts the block axis minor, so the step copies the
+    whole array into the kernel's row-major layout on entry and back on
+    exit — two copies, no more.  (A pool kept row-major between steps
+    avoids them, but an executable taking that layout does not survive
+    the persistent compile cache: reloaded, it expects the default
+    layout.)"""
+    monkeypatch.setattr(dispatch, "kernel_interpret", lambda backend: False)
+    policy = PrecisionPolicy.serve_default().overlay("M23")
+    put = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = jax.eval_shape(lambda k: T.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    if phase == "decode":
+        params = jax.eval_shape(
+            lambda p: prelimb_dense_params(p, 3, mla=cfg.mla), params)
+    L, N = cfg.n_layers, POOL_BLOCKS_CELL
+    bs = 64 if cfg.mla is not None else BLOCK
+    entries = pool_entry_shapes(cfg)
+    pools = [jax.ShapeDtypeStruct((L, N, bs) + e, F32, sharding=one_chip)
+             for e in entries]
+    slots, toks = (64, ((64, 1), I32)) if phase == "decode" else (
+        1, ((1, 128), I32))
+    rest = [((slots, 8), I32), ((slots,), I32), toks] + (
+        [((), I32)] if phase == "prefill" else [])
+    args = [put(params)] + pools + [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in rest]
+    make = make_paged_decode_step if phase == "decode" \
+        else make_paged_prefill_step
+    step = dispatch.pin_backend(make(cfg, policy), "pallas")
+    text = jax.jit(step, donate_argnums=POOL_ARGNUMS).lower(
+        *args).compile().as_text()
+
+    # the pools are the parameters after the weights' leaves, and alias the
+    # step's outputs 2 and 3
+    first = len(jax.tree_util.tree_leaves(params))
+    aliases = re.search(r"input_output_alias=\{ (.*?) \}", text).group(1)
+    for i, e in enumerate(entries):
+        shape = ",".join(map(str, (L, N, bs) + e))
+        assert re.search(rf"= f32\[{shape}\]\S* parameter\({first + i}\)",
+                         text)
+        assert re.search(rf"\{{{2 + i}\}}: \({first + i}, \{{\}}",
+                         aliases), aliases
+
+    pool_shapes = set()
+    for e in entries:
+        tail = ",".join(map(str, (bs,) + e))
+        pool_shapes |= {f"{L},{N},{tail}", f"{L * N},{tail}", f"{N},{tail}",
+                        f"1,{N},{tail}"}
+    insts = _instructions(text)
+    roots = {comp: op for comp, _, _, op, root in insts if root}
+    moves, relayouts = [], []
+    for _, name, result, op, _ in insts:
+        if not pool_shapes & set(re.findall(r"f32\[([\d,]+)\]", result)):
+            continue
+        if op == "fusion":   # allowed where it writes rows: a scatter
+            called = re.search(rf"%{re.escape(name)} = .*calls=%([\w.-]+)",
+                               text).group(1)
+            op = f"fusion of {roots[called]}"
+            if roots[called] == "scatter":
+                continue
+        if op == "copy" and result.startswith(f"f32[{L},{N},{bs},64]{{"):
+            relayouts.append(result)
+        elif op not in POOL_OPS:
+            moves.append((name, op))
+    assert not moves, moves
+    assert len(relayouts) == (2 if cfg.mla is not None else 0), relayouts
+    assert len(set(relayouts)) == len(relayouts)   # there and back
+
+    kernel = "mp_latent_paged_attention" if cfg.mla is not None \
+        else "mp_paged_attention"
+    calls = [line for line in text.splitlines()
+             if re.search(rf"%{kernel}(\.\d+)? = .* custom-call\(", line)]
+    assert len(calls) == (L if phase == "decode" else 0)
+    flat = [f"f32[{L * N},{bs},{','.join(map(str, e))}]" for e in entries]
+    for line in calls:
+        assert all(f in line for f in flat), line[:300]
+    if cfg.mla is not None:
+        fam = [re.compile(p) for p in json.loads(
+            (FAMILIES / "mla_attention.json").read_text())["patterns"]]
+        assert all(any(p.search(x) for p in fam) for x in calls)
